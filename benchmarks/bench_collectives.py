@@ -75,6 +75,7 @@ from benchmarks.common import is_quick, save_result
 from repro.core.fixed_point import FixedPointFormat
 from repro.dist.collectives import (dps_allreduce_mean,
                                     dps_allreduce_mean_tree)
+from repro.dist.sharding import make_mesh
 from repro.launch.hlo_stats import collective_wire_bytes, concat_bytes
 
 
@@ -446,7 +447,7 @@ def run():
         save_result("collectives", out)
         return out
 
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     size = (1 << 21) if is_quick() else (1 << 24)     # fp32 elements per rank
     iters = 3 if is_quick() else 20
     fmt = FixedPointFormat.create(3, 5)

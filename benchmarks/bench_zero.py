@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from benchmarks.common import is_quick, save_result
 from repro.core import qtrain
 from repro.core.dps import DPSHyper
+from repro.dist.sharding import make_mesh
 from repro.launch.hlo_stats import wire_bytes_summary
 from repro.models import lenet
 from repro.optim import SGDConfig, make_optimizer
@@ -62,7 +63,7 @@ def run():
         save_result("zero", out)
         return out
 
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     opt = make_optimizer(SGDConfig())
     params = lenet.init(jax.random.key(0))
     batch_n = 64 if is_quick() else 512

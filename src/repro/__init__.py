@@ -1,10 +1,1 @@
-"""repro: DPS (dynamic precision scaling) training system in JAX.
-
-Importing the package installs small version-compat aliases so the same
-source runs on the pinned jaxlib and on newer JAX releases (see
-:mod:`repro.compat`).
-"""
-
-from repro import compat as _compat
-
-_compat.install()
+"""repro: DPS (dynamic precision scaling) training system in JAX."""

@@ -74,7 +74,7 @@ from __future__ import annotations
 from typing import Dict, Iterable, Optional, Set, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.report import Report
 from repro.core import tagging

@@ -63,11 +63,11 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 
-import repro.compat  # noqa: F401  (installs the jax.shard_map shim)
 from repro.analysis import flow, hlo_audit, kernel_checks
 from repro.analysis.report import Report
 from repro.core import qtrain
 from repro.dist import collectives
+from repro.dist.sharding import make_mesh
 
 MODES = ("baseline", "tree", "per-layer", "zero", "zero-per-layer",
          "zero-overlap", "serve-decode")
@@ -75,7 +75,7 @@ MODES = ("baseline", "tree", "per-layer", "zero", "zero-per-layer",
 
 def _data_mesh():
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def _mode_qcfg(mode: str, n_ranks: int, wire_controller: str,

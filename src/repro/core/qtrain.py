@@ -530,10 +530,11 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
     the registry's dedicated ``wire_grads`` domain, and the dispatch-leg
     QuantStats feed that domain's controller (and only it — compute
     controllers never see wire events).  The path engages only on pure
-    data-parallel meshes (every non-``data_axis`` mesh axis of size 1):
-    JAX 0.4's partial-manual ``shard_map`` (``auto=``) miscompiles the
-    mixed GSPMD/manual case, so tensor-parallel meshes fall back to the
-    implicit psum with a warning.  On a single-device mesh (or
+    data-parallel meshes (every non-``data_axis`` mesh axis of size 1);
+    tensor-parallel meshes fall back to the implicit psum with a warning.
+    The restriction dates from a JAX 0.4 miscompile of partial-manual
+    ``shard_map``; whether JAX 0.9's ``axis_names=`` form composes with
+    the tensor-parallel rules is unverified.  On a single-device mesh (or
     ``mesh=None``) the path degrades to the identity all-reduce: the step
     is bit-identical to the uncompressed one.
 

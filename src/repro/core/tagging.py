@@ -36,7 +36,7 @@ import contextlib
 from typing import Any, Iterator, Optional, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 from jax.interpreters import ad, batching, mlir
 
 TAG_PRIMITIVE_NAME = "dps_tag"

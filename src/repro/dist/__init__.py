@@ -39,9 +39,10 @@ Training integration — ``QuantConfig.grad_allreduce_bits``
 The knob that turns the codec into the gradient hot path::
 
     from repro.core import qtrain
+    from repro.dist import make_mesh
     from repro.optim import SGDConfig, make_optimizer
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     qcfg = qtrain.QuantConfig(grad_allreduce_bits=8)
     step = qtrain.make_train_step(loss_fn, make_optimizer(SGDConfig()),
                                   qcfg, mesh=mesh)
@@ -62,7 +63,7 @@ spelling is ``repro.launch.train --grad-allreduce-bits 8``.
 
 from repro.dist.sharding import (LogicalRules, ZeroPartitioner, axis_rules,
                                  current_mesh_rules, logical_constraint,
-                                 model_axis_size, tree_specs)
+                                 make_mesh, model_axis_size, tree_specs)
 from repro.dist.collectives import (dps_allgather_params, dps_allreduce_mean,
                                     dps_allreduce_mean_tree,
                                     dps_reduce_scatter_mean, psum_stats,
@@ -71,7 +72,7 @@ from repro.dist.collectives import (dps_allgather_params, dps_allreduce_mean,
 
 __all__ = [
     "LogicalRules", "ZeroPartitioner", "axis_rules", "current_mesh_rules",
-    "logical_constraint", "model_axis_size", "tree_specs",
+    "logical_constraint", "make_mesh", "model_axis_size", "tree_specs",
     "dps_allgather_params", "dps_allreduce_mean", "dps_allreduce_mean_tree",
     "dps_reduce_scatter_mean", "psum_stats", "resolve_domain_format",
     "wire_decode", "wire_encode", "wire_format",

@@ -16,7 +16,7 @@ own leg's ⟨IL, FL⟩ (:func:`resolve_domain_format`).
 Codec backends: on TPU the encode runs as the fused Pallas
 ``dps_quant_wire`` kernel (one read-x/write-wire HBM pass, stats ride in
 SMEM); elsewhere it runs as plain jnp ops.  ``backend="auto"`` picks per
-``jax.default_backend()``; both backends are bit-exact against
+:func:`repro.device.on_tpu`; both backends are bit-exact against
 ``repro.kernels.ref.dps_quant_wire_ref``.
 
 Formats may be **per-group**: an ⟨IL, FL⟩ of shape ``[G]`` splits the
@@ -49,6 +49,7 @@ from repro.core import tagging
 from repro.core.fixed_point import (FixedPointFormat, QuantStats,
                                     ROUND_NEAREST, ROUND_STOCHASTIC, exp2_int,
                                     wire_quantize)
+from repro.device import on_tpu
 
 # int8 wire capacity: IL + FL beyond this saturates grid integers.
 WIRE_BITS = 8
@@ -229,12 +230,22 @@ class GroupLayout:
             out[off // self.quantum:(off + pad) // self.quantum] = g
         return out
 
-    def mask(self) -> np.ndarray:
-        """float32 ``[total]`` validity (1 on payload, 0 on padding)."""
-        out = np.zeros((self.total,), np.float32)
-        for g, (off, size) in enumerate(zip(self.offsets, self.group_sizes)):
-            out[off:off + size] = 1.0
-        return out
+    def mask(self) -> jax.Array:
+        """float32 ``[total]`` validity (1 on payload, 0 on padding).
+
+        Built on the device from per-tile payload counts: a host array of
+        ``total`` floats would enter the compiled step as a constant as
+        large as the whole gradient."""
+        valid = np.zeros((self.tiles,), np.int32)
+        for off, size in zip(self.offsets, self.group_sizes):
+            t0, full = off // self.quantum, size // self.quantum
+            valid[t0:t0 + full] = self.quantum
+            if size % self.quantum:
+                valid[t0 + full] = size % self.quantum
+        lane = jax.lax.broadcasted_iota(jnp.int32,
+                                        (self.tiles, self.quantum), 1)
+        return (lane < jnp.asarray(valid)[:, None]).astype(
+            jnp.float32).reshape(self.total)
 
     def align(self, flat: jax.Array) -> jax.Array:
         """Contiguous ``[size]`` payload → aligned ``[total]`` buffer
@@ -299,9 +310,28 @@ def _check_group_sizes(fmt: FixedPointFormat, group_sizes, total: int,
             f"(one per format-table row) summing to {what} = {total}")
 
 
+def wire_all_to_all(payload: jax.Array, axis_name) -> jax.Array:
+    """Tiled ``all_to_all`` of an ``(n, chunk)`` payload over its rows.
+
+    The payload travels as ``(n, chunk / lanes, lanes)`` blocks when a
+    128-multiple lane width divides the chunk: the TPU compiler's
+    all-to-all of a 2-D int8 array takes compile time and host memory
+    linear in the chunk (a minute and several GB at 3.4e7 elements), the
+    3-D form compiles in about a second.  Same bytes, same order."""
+    n, chunk = payload.shape
+    lanes = next((w for w in (1024, 128) if chunk % w == 0), None)
+    if lanes is None:
+        return jax.lax.all_to_all(payload, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=True)
+    out = jax.lax.all_to_all(payload.reshape(n, chunk // lanes, lanes),
+                             axis_name, split_axis=0, concat_axis=0,
+                             tiled=True)
+    return out.reshape(n, chunk)
+
+
 def _resolve_backend(backend: str) -> str:
     if backend == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "jnp"
+        return "kernel" if on_tpu() else "jnp"
     if backend not in ("kernel", "jnp"):
         raise ValueError(f"unknown wire codec backend {backend!r}; "
                          "expected 'auto', 'kernel' or 'jnp'")
@@ -500,7 +530,7 @@ def wire_encode(x: jax.Array, fmt: FixedPointFormat, *,
         layout = group_layout(group_sizes or _equal_group_sizes(n, groups))
         wire_al, stats = _encode_aligned(
             layout.align(x.reshape(-1)), fmt, jnp.asarray(layout.tile_groups()),
-            jnp.asarray(layout.mask()),
+            layout.mask(),
             bits=layout.align(bits) if bits is not None else None,
             mode=mode, backend="kernel", quantum=layout.quantum,
             compute_stats=compute_stats)
@@ -642,8 +672,7 @@ def dps_allreduce_mean(x: jax.Array, formats, axis_name,
                                   backend=be)
         wire = _pad_reshape(wire, pad, (n, chunk))
         wire = tagging.tag(wire, "wire_payload", leg="dispatch")
-        wire = jax.lax.all_to_all(wire, axis_name, split_axis=0,
-                                  concat_axis=0, tiled=True)    # (n, chunk)
+        wire = wire_all_to_all(wire, axis_name)    # (n, chunk)
         # receive: fused int8 decode-reduce on the kernel backend — the
         # decoded fp32 (n, chunk) intermediate never exists in HBM.
         part = _wire_reduce(wire, fmt, None, backend=be, quantum=q)
@@ -712,7 +741,7 @@ def _aligned_rs_snap(x_al, fmt: FixedPointFormat,
     n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     tg_all = jnp.asarray(layout.tile_groups())
-    mask = jnp.asarray(layout.mask())
+    mask = layout.mask()
     stochastic = mode == ROUND_STOCHASTIC
     if encode_leg1 is None:
         bits1 = (layout.align(jax.random.bits(k1, shape=(layout.size,),
@@ -726,8 +755,7 @@ def _aligned_rs_snap(x_al, fmt: FixedPointFormat,
 
     payload = tagging.tag(wire_al.reshape(n, layout.chunk), "wire_payload",
                           leg="dispatch")
-    wire = jax.lax.all_to_all(payload, axis_name,
-                              split_axis=0, concat_axis=0, tiled=True)
+    wire = wire_all_to_all(payload, axis_name)
     # this rank's chunk covers tiles [idx·tpc, (idx+1)·tpc) of the layout
     tpc = layout.chunk // layout.quantum
     my_tg = jax.lax.dynamic_slice(tg_all, (idx * tpc,), (tpc,))
@@ -844,8 +872,7 @@ def dps_reduce_scatter_mean(x: jax.Array, formats, axis_name,
                 mode=mode)
             wire = _pad_reshape(wire, pad, (n, chunk))
             wire = tagging.tag(wire, "wire_payload", leg="dispatch")
-            wire = jax.lax.all_to_all(wire, axis_name, split_axis=0,
-                                      concat_axis=0, tiled=True)
+            wire = wire_all_to_all(wire, axis_name)
             # decode with the formats of THIS rank's chunk positions
             gid_pad = np.pad(gid, (0, pad))
             my_gid = jax.lax.dynamic_slice(jnp.asarray(gid_pad),
@@ -859,8 +886,7 @@ def dps_reduce_scatter_mean(x: jax.Array, formats, axis_name,
                                   mode=mode, backend=be)
         wire = _pad_reshape(wire, pad, (n, chunk))
         wire = tagging.tag(wire, "wire_payload", leg="dispatch")
-        wire = jax.lax.all_to_all(wire, axis_name, split_axis=0,
-                                  concat_axis=0, tiled=True)     # (n, chunk)
+        wire = wire_all_to_all(wire, axis_name)     # (n, chunk)
         # fused decode-reduce on the kernel backend (no fp32 (n, chunk)
         # in HBM)
         shard = _wire_reduce(wire, fmt, None, backend=be, quantum=q)
@@ -1043,9 +1069,7 @@ def dps_allreduce_mean_tree(tree, formats, axis_name,
             buf, stats = encode_leg1(None, None)
             payload = tagging.tag(buf.reshape(n, chunk), "wire_payload",
                                   leg="dispatch")
-            wire = jax.lax.all_to_all(payload, axis_name,
-                                      split_axis=0, concat_axis=0,
-                                      tiled=True)
+            wire = wire_all_to_all(payload, axis_name)
             part = _wire_reduce(wire, fmt, None, backend=be, quantum=q)
             # gather-leg bits keyed by global leaf index (rank-invariant
             # k2s stream, same contract as _aligned_rs_snap) so the

@@ -78,7 +78,8 @@ from repro.dist.collectives import (_aligned_allreduce_mean,
                                     _resolve_backend, _resolve_quantum,
                                     _validate_capacity, _wire_reduce,
                                     group_layout, resolve_domain_format,
-                                    wire_decode, wire_encode)
+                                    wire_all_to_all, wire_decode,
+                                    wire_encode)
 
 # Default bucket granularity, in elements.  Small enough that a LeNet-
 # scale tree still splits into a few buckets (so the schedule is
@@ -330,8 +331,7 @@ def bucketed_allreduce_mean_tree(tree, formats, axis_name, key,
                     leaf_stats[g] = s
                 payload = tagging.tag(buf.reshape(n, chunk), "wire_payload",
                                       leg="dispatch")
-                wire = jax.lax.all_to_all(payload, axis_name, split_axis=0,
-                                          concat_axis=0, tiled=True)
+                wire = wire_all_to_all(payload, axis_name)
                 part = _wire_reduce(wire, fmt, None, backend=be, quantum=q)
                 if mode == ROUND_STOCHASTIC:
                     bits2 = jax.lax.dynamic_slice(
@@ -551,7 +551,7 @@ def zero_allgather_params(shard: jax.Array, formats, axis_name, key, *,
             tpc = lay.chunk // lay.quantum
             my_tg = jax.lax.dynamic_slice(tg_all, (idx * tpc,), (tpc,))
             my_mask = jax.lax.dynamic_slice(
-                jnp.asarray(lay.mask()), (idx * lay.chunk,), (lay.chunk,))
+                lay.mask(), (idx * lay.chunk,), (lay.chunk,))
             soff = part.shard_offset(pb)
             seg = jax.lax.slice(shard, (soff,), (soff + lay.chunk,))
             if mode == ROUND_STOCHASTIC:

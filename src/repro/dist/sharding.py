@@ -30,7 +30,7 @@ from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # One candidate assignment: a single mesh axis or a tuple of mesh axes that
 # shard a dimension jointly (e.g. batch over ("pod", "data")).
@@ -397,6 +397,20 @@ class GroupAlignedPartitioner:
 # ---------------------------------------------------------------------------
 # Mesh + rules context.
 # ---------------------------------------------------------------------------
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """A device mesh whose axes are all ``AxisType.Auto``.
+
+    The models place arrays through logical rules and sharding constraints
+    that the compiler propagates (:func:`logical_constraint`), which is the
+    Auto axis semantics.  ``jax.make_mesh`` makes Explicit axes by default,
+    under which every gather and matmul on a sharded operand would need an
+    explicit ``out_sharding``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
 
 class _Ctx(threading.local):
     def __init__(self):

@@ -88,11 +88,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; support both so
-# the kernel compiles against the pinned jaxlib and newer releases alike.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 # stats accumulator layout (must match ref.dps_quant_ref)
 N_STATS = 7
 _IDX_COUNT, _IDX_NZ, _IDX_OVER, _IDX_AERR, _IDX_RERR, _IDX_ASUM, _IDX_MAX = range(7)
@@ -176,9 +171,25 @@ KERNEL_SIGNATURES = {
 
 def _exp2i(n):
     """Bit-exact 2^n inside the kernel (jnp.exp2 is inexact on some
-    backends; matches fixed_point.exp2_int)."""
-    n = jnp.clip(n, -126, 127)
+    backends; matches fixed_point.exp2_int).
+
+    ``n`` is a scalar read from SMEM; Mosaic bit-casts vectors only, so the
+    exponent is splatted to a (1, 1) vector first.  The result broadcasts
+    against any tile like the scalar would."""
+    n = jnp.broadcast_to(jnp.clip(n, -126, 127), (1, 1))
     return jax.lax.bitcast_convert_type((n + 127) << 23, jnp.float32)
+
+
+def _uniform24(bits):
+    """Top 24 bits of a uint32 (or int32) tile as a uniform in [0, 1).
+
+    Mosaic has no uint32 -> float32 cast: the bits are reinterpreted as
+    int32 and shifted logically, so the 24-bit value is non-negative and
+    converts exactly through int32."""
+    if bits.dtype != jnp.int32:
+        bits = jax.lax.bitcast_convert_type(bits, jnp.int32)
+    top = jax.lax.shift_right_logical(bits, jnp.int32(32 - _U_BITS))
+    return top.astype(jnp.float32) * _U_SCALE
 
 
 def _kernel(fmt_ref,            # SMEM: (3,) int32 [il, fl, seed]
@@ -212,10 +223,10 @@ def _kernel(fmt_ref,            # SMEM: (3,) int32 [il, fl, seed]
             # TPU fast path: no bits operand traffic.  Seed is decorrelated
             # per grid tile so every tile draws an independent stream.
             pltpu.prng_seed(fmt_ref[2] + i * pl.num_programs(1) + j)
-            bits = pltpu.prng_random_bits(x.shape).astype(jnp.uint32)
+            bits = pltpu.prng_random_bits(x.shape)
         else:
             bits = bits_ref[...]
-        u = (bits >> (32 - _U_BITS)).astype(jnp.float32) * _U_SCALE
+        u = _uniform24(bits)
         q_int = jnp.floor(yc + u)
     else:
         q_int = jnp.floor(yc + 0.5)
@@ -305,7 +316,7 @@ def _pallas_quant(x: jax.Array, fmt3: jax.Array, bits: jax.Array,
             jax.ShapeDtypeStruct((Mp, Np), out_dtype),
             jax.ShapeDtypeStruct((N_STATS,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -318,7 +329,7 @@ def _pallas_quant(x: jax.Array, fmt3: jax.Array, bits: jax.Array,
 def dps_quant_pallas(x: jax.Array, fmt3: jax.Array, bits: jax.Array,
                      mask: jax.Array | None = None,
                      *, stochastic: bool = True, use_onchip_prng: bool = False,
-                     block=DEFAULT_BLOCK, interpret: bool = True):
+                     block=DEFAULT_BLOCK, interpret: bool = False):
     """Run the fused kernel on a 2-D fp32/bf16 array.
 
     ``fmt3`` = int32[3] = [il, fl, seed].  ``bits`` uint32, same shape as x
@@ -337,7 +348,7 @@ def dps_quant_wire_pallas(x: jax.Array, fmt3: jax.Array, bits: jax.Array,
                           mask: jax.Array | None = None,
                           *, stochastic: bool = True,
                           use_onchip_prng: bool = False,
-                          block=DEFAULT_BLOCK, interpret: bool = True):
+                          block=DEFAULT_BLOCK, interpret: bool = False):
     """Fused quantize → **int8 wire** + stats in one read-x/write-wire pass.
 
     Same contract as :func:`dps_quant_pallas` except the tensor output is
@@ -386,10 +397,10 @@ def _group_kernel(fmt_ref,           # SMEM: (G, 2) int32 [[il, fl], ...]
     if stochastic:
         if use_onchip_prng:
             pltpu.prng_seed(seed_ref[0] + t)
-            bits = pltpu.prng_random_bits(x.shape).astype(jnp.uint32)
+            bits = pltpu.prng_random_bits(x.shape)
         else:
             bits = bits_ref[...]
-        u = (bits >> (32 - _U_BITS)).astype(jnp.float32) * _U_SCALE
+        u = _uniform24(bits)
         q_int = jnp.floor(yc + u)
     else:
         q_int = jnp.floor(yc + 0.5)
@@ -439,7 +450,7 @@ def dps_quant_group_wire_pallas(x: jax.Array, fmt_tab: jax.Array,
                                 *, stochastic: bool = True,
                                 use_onchip_prng: bool = False,
                                 quantum: int = DEFAULT_GROUP_QUANTUM,
-                                interpret: bool = True,
+                                interpret: bool = False,
                                 emit_stats: bool = True):
     """Per-group ⟨IL, FL⟩ wire encode of a group-aligned flat buffer.
 
@@ -492,7 +503,7 @@ def dps_quant_group_wire_pallas(x: jax.Array, fmt_tab: jax.Array,
             out_specs=out_specs,
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -524,7 +535,7 @@ def _wire_reduce_kernel(fmt_ref,     # SMEM: (G, 2) int32 format table
 def dps_wire_reduce_pallas(wire: jax.Array, fmt_tab: jax.Array,
                            tile_group: jax.Array,
                            *, quantum: int = DEFAULT_GROUP_QUANTUM,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """Fused decode → sum → mean over the rank axis of an int8 payload.
 
     ``wire``: int8 ``[n_ranks, chunk]`` (chunk a quantum multiple) — the
@@ -552,7 +563,7 @@ def dps_wire_reduce_pallas(wire: jax.Array, fmt_tab: jax.Array,
             out_specs=pl.BlockSpec((bm, bn), lambda t, *_: (t, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((tiles * bm, bn), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

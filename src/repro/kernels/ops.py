@@ -4,9 +4,9 @@
 :class:`~repro.core.fixed_point.FixedPointFormat`, reshapes to the kernel's
 2-D tiling, and adapts the raw stats vector back into ``QuantStats``.
 
-On this (CPU) container the kernel runs in Pallas interpret mode; on TPU the
-same call lowers to Mosaic.  ``onchip_prng=True`` selects the PRNG-in-kernel
-variant (TPU only — see kernel docstring).
+``interpret=None`` resolves from the platform: Mosaic on a TPU, the Pallas
+interpreter elsewhere (the CPU tests).  ``onchip_prng=True`` selects the
+PRNG-in-kernel variant (TPU only — see kernel docstring).
 """
 
 from __future__ import annotations
@@ -18,22 +18,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.fixed_point import FixedPointFormat, QuantStats
+from repro.device import on_tpu
 from repro.kernels import ref as ref_lib
 from repro.kernels.dps_quant import (DEFAULT_BLOCK, DEFAULT_GROUP_QUANTUM,
                                      dps_quant_pallas,
                                      dps_quant_group_wire_pallas,
                                      dps_quant_wire_pallas,
                                      dps_wire_reduce_pallas, group_block)
-
-_ON_TPU = None
-
-
-def _on_tpu() -> bool:
-    global _ON_TPU
-    if _ON_TPU is None:
-        _ON_TPU = jax.default_backend() == "tpu"
-    return _ON_TPU
-
 
 # ---------------------------------------------------------------------------
 # Static call-site geometry — what each wrapper WOULD launch, computed
@@ -166,7 +157,7 @@ def _fold_and_call(pallas_fn, x, fmt, *, key, bits, stochastic, onchip_prng,
                    block, interpret):
     """Shared any-rank → 2-D tiling adapter around a dps_quant kernel."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     orig_shape = x.shape
     n = x.size
     # fold to 2-D with a 128-lane-friendly minor dim; zero-pad the tail (the
@@ -268,7 +259,7 @@ def dps_quantize_wire_grouped(x: jax.Array, fmt: FixedPointFormat,
     and returns ``None``.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     n = x.size
     if stochastic and not onchip_prng:
         if bits is None:
@@ -308,7 +299,7 @@ def dps_wire_reduce(wire: jax.Array, fmt: FixedPointFormat,
     fp32 intermediate in HBM.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     n, chunk = wire.shape
     tiles = -(-chunk // quantum)
     pad = tiles * quantum - chunk

@@ -39,18 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dps_quant import _CompilerParams, _exp2i
+from repro.device import on_tpu
+from repro.kernels.dps_quant import _exp2i
 
 # matches models.attention.NEG_INF: finite, so masked-row softmax math
 # stays NaN-free (exp(NEG_INF - m) underflows to exactly 0.0)
 NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no devices configured
-        return False
 
 
 def _page_attn_step(q, kw, vw, fl_k, fl_v, base, seq_len, m, l, acc, *,
@@ -60,41 +54,44 @@ def _page_attn_step(q, kw, vw, fl_k, fl_v, base, seq_len, m, l, acc, *,
     Shared verbatim by the kernel body and the jnp reference so the two are
     bit-exact: identical op sequence on identical shapes.
 
-    q: (H, Dh) fp32 — the decode-step query for one batch row.
+    q: (KV, G, Dh) fp32 — the decode-step query for one batch row, its H
+        heads grouped by the KV head that serves them (GQA, G = H / KV).
     kw/vw: (page, KV, Dh) int8 grid integers (or fp32 when paging runs at
         ``bits=None``; then FL = 0 and the dequant multiply is exact ×1.0).
     fl_k/fl_v: scalar int32 — this page's FL (per-page grid exponent).
     base: scalar int32 — first absolute position covered by this page.
     seq_len: scalar int32 — valid length of this row (positions ≥ len mask
         to NEG_INF, so trash-page garbage never reaches the output).
-    m/l/acc: (H, 1)/(H, 1)/(H, Dh) fp32 running max / normalizer / value.
-    """
-    ps, KV, Dh = kw.shape
-    H = q.shape[0]
-    G = H // KV
+    m/l/acc: (KV, G, 1)/(KV, G, 1)/(KV, G, Dh) fp32 running max /
+        normalizer / value.
 
+    Both contractions batch over the KV head (leading on q, second on the
+    page), so each keeps a non-contracting dimension on either side — the
+    form Mosaic lowers.
+    """
+    ps = kw.shape[0]
     k = kw.astype(jnp.float32) * _exp2i(-fl_k)
     v = vw.astype(jnp.float32) * _exp2i(-fl_v)
-    # GQA: each KV head serves H/KV query heads (broadcast, not repeat —
-    # broadcast_to lowers to a no-copy view on TPU)
-    kh = jnp.broadcast_to(k[:, :, None, :], (ps, KV, G, Dh)).reshape(ps, H, Dh)
-    vh = jnp.broadcast_to(v[:, :, None, :], (ps, KV, G, Dh)).reshape(ps, H, Dh)
 
-    # scores (H, ps): contract Dh, batch over H
-    s = jax.lax.dot_general(q, kh, (((1,), (2,)), ((0,), (1,))),
-                            preferred_element_type=jnp.float32)
-    idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+    # f32 contractions at full precision on the MXU: the step is bound by
+    # the page reads, and a one-pass bf16 product would round q and k to
+    # 8 mantissa bits
+    hp = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    # scores (KV, G, ps): contract Dh, batch over KV
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
+                            precision=hp, preferred_element_type=jnp.float32)
+    idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
     valid = (idx < seq_len).astype(jnp.float32)
     s = s * scale + jnp.where(valid > 0.0, 0.0, NEG_INF)
 
-    bm = jnp.max(s, axis=1, keepdims=True)
+    bm = jnp.max(s, axis=2, keepdims=True)
     new_m = jnp.maximum(m, bm)
     p = jnp.exp(s - new_m) * valid
     corr = jnp.exp(m - new_m)
-    new_l = l * corr + jnp.sum(p, axis=1, keepdims=True)
-    # pv (H, Dh): contract ps, batch over H
-    pv = jax.lax.dot_general(p, vh, (((1,), (0,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32)
+    new_l = l * corr + jnp.sum(p, axis=2, keepdims=True)
+    # pv (KV, G, Dh): contract ps, batch over KV
+    pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
+                             precision=hp, preferred_element_type=jnp.float32)
     new_acc = acc * corr + pv
     return new_m, new_l, new_acc
 
@@ -107,10 +104,10 @@ def _finalize(m, l, acc):
 def _paged_attn_kernel(ptab_ref,    # SMEM: (B, P) int32 page table
                        fmt_ref,     # SMEM: (n_pages, 2) int32 [fl_k, fl_v]
                        lens_ref,    # SMEM: (B,) int32 valid sequence lengths
-                       q_ref,       # VMEM: (1, H, Dh) query block
+                       q_ref,       # VMEM: (1, KV, G, Dh) query block
                        k_ref,       # VMEM: (1, page, KV, Dh) gathered K page
                        v_ref,       # VMEM: (1, page, KV, Dh) gathered V page
-                       out_ref,     # VMEM out: (1, H, Dh) fp32
+                       out_ref,     # VMEM out: (1, KV, G, Dh) fp32
                        m_ref, l_ref, acc_ref,   # VMEM scratch accumulators
                        *, page_size: int, scale: float):
     b = pl.program_id(0)
@@ -140,7 +137,7 @@ def _paged_attn_kernel(ptab_ref,    # SMEM: (B, P) int32 page table
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attn_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       fmt: jax.Array, ptab: jax.Array, lens: jax.Array,
-                      *, scale: float, interpret: bool = True):
+                      *, scale: float, interpret: bool = False):
     """Fused paged decode attention; one launch per decode step.
 
     ``q``: fp32 (B, H, Dh) single-token queries.  ``k_pages``/``v_pages``:
@@ -152,15 +149,16 @@ def paged_attn_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """
     B, H, Dh = q.shape
     n_pages, ps, KV, _ = k_pages.shape
+    G = H // KV
     P = ptab.shape[1]
     kernel = functools.partial(_paged_attn_kernel, page_size=ps, scale=scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, P),
             in_specs=[
-                pl.BlockSpec((1, H, Dh), lambda b, p, *_: (b, 0, 0)),
+                pl.BlockSpec((1, KV, G, Dh), lambda b, p, *_: (b, 0, 0, 0)),
                 # the page gather: scalar-prefetch refs arrive as trailing
                 # index_map args, so the block index is ptab[b, p]
                 pl.BlockSpec((1, ps, KV, Dh),
@@ -168,19 +166,21 @@ def paged_attn_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 pl.BlockSpec((1, ps, KV, Dh),
                              lambda b, p, ptab, fmt, lens: (ptab[b, p], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, H, Dh), lambda b, p, *_: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, KV, G, Dh),
+                                   lambda b, p, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, Dh), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, Dh), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(ptab, fmt, lens, q, k_pages, v_pages)
+    )(ptab, fmt, lens, q.reshape(B, KV, G, Dh), k_pages, v_pages)
+    return out.reshape(B, H, Dh)
 
 
 def _paged_attn_jnp(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -194,7 +194,8 @@ def _paged_attn_jnp(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     materializes the dequantized pool: one page is decoded per scan step.
     """
     B, H, Dh = q.shape
-    ps = k_pages.shape[1]
+    ps, KV = k_pages.shape[1:3]
+    G = H // KV
     P = ptab.shape[1]
 
     def one_row(qb, ptab_b, len_b):
@@ -207,15 +208,16 @@ def _paged_attn_jnp(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                         p * ps, len_b, m, l, acc, scale=scale)
             return (m, l, acc), None
 
-        init = (jnp.full((H, 1), NEG_INF, jnp.float32),
-                jnp.zeros((H, 1), jnp.float32),
-                jnp.zeros((H, Dh), jnp.float32))
+        init = (jnp.full((KV, G, 1), NEG_INF, jnp.float32),
+                jnp.zeros((KV, G, 1), jnp.float32),
+                jnp.zeros((KV, G, Dh), jnp.float32))
         (m, l, acc), _ = jax.lax.scan(body, init, jnp.arange(P, dtype=jnp.int32))
-        return _finalize(m, l, acc)
+        return _finalize(m, l, acc).reshape(H, Dh)
 
     # unrolled over B (small at serving batch sizes) rather than vmapped:
     # vmap batches the dot_generals into different contraction shapes, which
     # need not round identically to the kernel's per-row grid steps.
+    q = q.reshape(B, KV, G, Dh)
     return jnp.stack([one_row(q[b], ptab[b], lens[b]) for b in range(B)])
 
 
@@ -230,10 +232,10 @@ def paged_decode_attn(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Pallas inside the serving loop would pay a per-step lowering tax).
     """
     if backend == "auto":
-        backend = "kernel" if _on_tpu() else "jnp"
+        backend = "kernel" if on_tpu() else "jnp"
     if backend == "kernel":
         if interpret is None:
-            interpret = not _on_tpu()
+            interpret = not on_tpu()
         return paged_attn_pallas(q, k_pages, v_pages, fmt, ptab, lens,
                                  scale=scale, interpret=interpret)
     if backend != "jnp":

@@ -2,7 +2,7 @@
 
 Two views of the same parse:
 
-* :func:`collective_bytes` — per-collective-type max-operand bytes (the
+* :func:`collective_bytes` — per-collective-type payload bytes (the
   dry-run's historical metric; kept for the roofline JSON schema).
 * :func:`collective_wire_bytes` — per-(op, dtype) **wire** bytes under the
   ring-transfer model: an all-reduce moves ~2× its payload over the
@@ -27,6 +27,9 @@ COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
 _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([\d,]*)\]")
 _ASSIGN_RE = re.compile(r"(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*)")
+# replica groups spelled out ({{0,1,..},..}) or in iota form ([G,N]<=[..])
+_GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([\d,]*)\}")
+_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[\d+,(\d+)\]")
 _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                 "u8": 1, "pred": 1, "s64": 8, "u64": 8, "f64": 8, "s16": 2,
                 "u16": 2, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
@@ -100,25 +103,38 @@ def _instructions(hlo_text: str, op_names: Iterable[str]
             break
 
 
-def _collective_instructions(hlo_text: str):
-    """Yield ``(op, dtype, payload_bytes)`` per collective instruction.
+def _group_size(line: str) -> int:
+    """Ranks per replica group of a collective line (1 when unstated)."""
+    m = _GROUPS_LIST_RE.search(line)
+    if m:
+        return len([r for r in m.group(1).split(",") if r])
+    m = _GROUPS_IOTA_RE.search(line)
+    return int(m.group(1)) if m else 1
 
-    Payload = max shape on the instruction (covers the full-tensor side of
-    an all-reduce / all-gather / reduce-scatter) — except ``all-to-all``,
-    whose CPU lowering decomposes into a tuple of per-rank chunks
-    ``(s8[1,c], ...×n) all-to-all(...)``; there the payload is the *sum*
-    of the result-tuple shapes (equal to the single-array form's full
-    shape), not one chunk.
+
+def _collective_instructions(hlo_text: str):
+    """Yield ``(op, dtype, payload_bytes)`` per collective result.
+
+    Payload = the full-tensor side of the collective.  Current HLO text
+    prints operands by name only, so only the result shapes are on the
+    line.  Each element of a result contributes its own bytes under its own
+    dtype: a combined ``(f32[a], f32[b]) all-reduce`` carries both
+    tensors, and the CPU lowering of ``all-to-all`` returns one
+    ``s8[1,c]`` chunk per rank.  A ``reduce-scatter`` result is one rank's
+    shard, so its payload is the result times the replica-group size.  An
+    async ``-start`` result also holds the operand, so there the largest
+    shape stands for the payload.
     """
     for ins in _instructions(hlo_text, COLLECTIVE_OPS):
         if not ins.shapes:
             continue
-        if ins.op == "all-to-all":
-            use = ins.result_shapes or ins.shapes
-            yield ins.op, use[0][0], float(sum(b for _, b in use))
-        else:
+        if not ins.result_shapes or f"{ins.op}-start(" in ins.line:
             dtype, nbytes = max(ins.shapes, key=lambda t: t[1])
             yield ins.op, dtype, float(nbytes)
+            continue
+        mult = _group_size(ins.line) if ins.op == "reduce-scatter" else 1
+        for dtype, nbytes in ins.result_shapes:
+            yield ins.op, dtype, float(nbytes * mult)
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, float]:

@@ -9,20 +9,10 @@ most expensive — see EXPERIMENTS §Roofline).
 
 from __future__ import annotations
 
-import jax
+from repro.dist.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def make_host_mesh():
-    """Whatever devices exist, as a 1-D data mesh (tests / smoke)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh(shape, axes)

@@ -7,20 +7,28 @@ trace is many users with mixed prompt/generation lengths and Poisson
 arrivals, so slots churn: the engine retires finished rows and admits new
 requests without recompiling (page tables and positions are step inputs).
 
-Smoke scale (CPU container):
+Smoke scale (CPU):
   PYTHONPATH=src python -m repro.launch.serve --arch llama3_2_3b --smoke \
       --requests 8 --slots 4 --page-size 4
+
+Published widths at reduced depth on a TPU (a 16-token page holds
+16·8·128 elements, a multiple of the grouped kernel's 4096):
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3_2_3b \
+      --layers 4 --requests 16 --slots 8 --page-size 16 --min-prompt 128 \
+      --max-prompt 512 --min-new 32 --max-new 64
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 from collections import Counter
 
 import numpy as np
 import jax
 
 from repro.configs.base import get_config, smoke as smoke_cfg
+from repro.device import enable_compile_cache
 from repro.models import registry
 from repro.models.common import init_params
 from repro.serve import (Engine, EngineConfig, PagedLayout, supports_paging,
@@ -45,6 +53,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="override the config's n_layers (depth cut only; "
+                         "every width stays as published)")
     ap.add_argument("--requests", type=int, default=8,
                     help="synthetic trace length (distinct users)")
     ap.add_argument("--slots", type=int, default=4,
@@ -75,9 +86,12 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_cfg(cfg)
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     if not supports_paging(cfg):
         raise SystemExit(f"{cfg.name}: family {cfg.family!r} has no paged "
                          f"decode path (GQA models only)")
@@ -106,6 +120,7 @@ def main(argv=None):
     print(f"layout: {layout.n_pages} pages × {layout.page_size} tok, "
           f"{layout.batch_slots} slots ({mode}, {bits} pages, "
           f"attn={eng._attn_backend}, encode={eng._enc_backend})")
+    print(f"completed {int(m['completed'])}/{len(reqs)} requests")
     print(f"served {len(reqs)} requests, {int(m['total_tokens'])} tokens "
           f"in {m['wall_s']:.3f}s -> {m['tokens_per_s']:.1f} tok/s")
     print(f"decode: {int(m['decode_steps'])} steps, mean occupancy "

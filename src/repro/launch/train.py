@@ -19,9 +19,14 @@ Production behaviors implemented here:
   * straggler/step watchdog: a step exceeding ``--step-timeout`` seconds
     raises, the driver checkpoints on the way down.
 
-Smoke scale (CPU container):
+Smoke scale (CPU):
   PYTHONPATH=src python -m repro.launch.train --arch llama3_2_3b --smoke \
       --steps 20 --batch 4 --seq 64
+
+Published widths at reduced depth (one TPU v5e holds four llama3_2_3b
+layers at batch 2, seq 2048):
+  PYTHONPATH=src python -m repro.launch.train --arch llama3_2_3b \
+      --layers 4 --steps 8 --batch 2 --seq 2048
 """
 
 from __future__ import annotations
@@ -41,8 +46,10 @@ import numpy as np
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs.base import get_config, smoke as smoke_cfg
 from repro.core import qtrain
+from repro.device import enable_compile_cache
 from repro.data import TokenStream, TokenStreamConfig
-from repro.dist.sharding import DEFAULT_RULES, LogicalRules, axis_rules
+from repro.dist.sharding import (DEFAULT_RULES, LogicalRules, axis_rules,
+                                 make_mesh)
 from repro.launch import specs as specs_lib
 from repro.models import registry
 from repro.models.common import init_params
@@ -71,17 +78,14 @@ def build(cfg, qcfg, opt_cfg, mesh=None, faults=None):
     step_fn = specs_lib.build_train_step(cfg, qcfg, opt, mesh=mesh,
                                          faults=faults)
     if mesh is not None:
-        if (getattr(step_fn, "wire_sync_active", False)
-                or getattr(step_fn, "zero_opt_active", False)):
-            # compressed all-reduce / ZeRO-1 = classic data parallelism:
-            # params replicate across the data axis (the shard_map pins them
-            # to P()); binding "fsdp" would re-gather every leaf per step.
-            # Under ZeRO the *optimizer state* shards instead, via the flat
-            # P("data") layout in train_state_shardings.
-            rules = LogicalRules(rules=tuple(
-                r for r in DEFAULT_RULES if r[0] != "fsdp"))
-        else:
-            rules = LogicalRules()
+        # classic data parallelism on the 1-D data mesh, with the fp32
+        # all-reduce, the int8 wire or ZeRO-1 alike: params replicate
+        # across the data axis (the wire's shard_map pins them to P());
+        # binding "fsdp" would re-gather every leaf per step.  Under ZeRO
+        # the *optimizer state* shards instead, via the flat P("data")
+        # layout in train_state_shardings.
+        rules = LogicalRules(rules=tuple(
+            r for r in DEFAULT_RULES if r[0] != "fsdp"))
         state_sh = specs_lib.train_state_shardings(cfg, mesh, rules, opt, qcfg)
         jitted = jax.jit(step_fn, in_shardings=(state_sh, None),
                          out_shardings=(state_sh, None), donate_argnums=(0,))
@@ -94,6 +98,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="override the config's n_layers (depth cut only; "
+                         "every width stays as published)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -191,9 +198,12 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_cfg(cfg)
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     n_dev = jax.device_count()
     zero_shards = n_dev if (args.zero_opt and n_dev > 1) else None
     guards = None
@@ -222,15 +232,26 @@ def main(argv=None):
         # checkpoint layout) is fixed before any tensor exists.  Under
         # --zero-opt this selects the group-aligned flat layout too.
         qcfg = specs_lib.per_layer_wire_qcfg(cfg, qcfg)
-    opt_cfg = (AdamWConfig(total_steps=args.steps) if args.optimizer == "adamw"
-               else SGDConfig())
+    # warmup is a tenth of the run, at most 100 steps: a short run leaves
+    # warmup and sees the whole cosine decay
+    opt_cfg = (AdamWConfig(total_steps=args.steps,
+                           warmup=min(100, args.steps // 10))
+               if args.optimizer == "adamw" else SGDConfig())
     mesh = None
-    if (args.grad_allreduce_bits is not None or zero_shards) and n_dev > 1:
-        # a pure data-parallel mesh over every local device — the regime the
-        # compressed all-reduce and ZeRO-1 target.  On one device qtrain
-        # degrades both paths to the replicated step, so no mesh is built.
-        mesh = jax.make_mesh((n_dev,), ("data",))
+    if n_dev > 1:
+        # a pure data-parallel mesh over every local device: the batch
+        # splits over it, and the gradients meet in the fp32 all-reduce or,
+        # with --grad-allreduce-bits, the int8 wire.  On one device no mesh
+        # is built and the wire and ZeRO-1 paths degrade to the plain step.
+        mesh = make_mesh((n_dev,), ("data",))
     opt, jitted = build(cfg, qcfg, opt_cfg, mesh=mesh, faults=faults)
+    if mesh is not None and args.grad_allreduce_bits is not None \
+            and not jitted.wire_sync_active:
+        raise SystemExit("--grad-allreduce-bits: the int8 gradient wire did "
+                         "not engage on this mesh")
+    print(f"step: {n_dev} device(s), wire_sync_active="
+          f"{jitted.wire_sync_active}, zero_opt_active="
+          f"{jitted.zero_opt_active}", flush=True)
 
     mod = registry(cfg.family)
     data = TokenStream(TokenStreamConfig(vocab=cfg.vocab, seq_len=args.seq,

@@ -25,7 +25,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import registry
 from repro.models.common import init_params, unembed
-from repro.kernels.paged_attn import _on_tpu
+from repro.device import on_tpu
 from repro.serve import cache as kvc
 from repro.serve.page_table import PageAllocator, PagedLayout, page_rows
 from repro.serve.scheduler import Request, Scheduler
@@ -76,11 +76,12 @@ class Engine:
 
         lay = self.layout
         page_elems = lay.page_size * cfg.n_kv_heads * cfg.head_dim
+        # "auto" is the kernel on a TPU and jnp elsewhere; a page the
+        # grouped kernel cannot tile raises rather than falling back
+        auto = "kernel" if on_tpu() else "jnp"
         self._attn_backend = (ecfg.attn_backend if ecfg.attn_backend != "auto"
-                              else ("kernel" if _on_tpu() else "jnp"))
-        eb = ecfg.encode_backend
-        if eb == "auto":
-            eb = "kernel" if _on_tpu() and page_elems % 4096 == 0 else "jnp"
+                              else auto)
+        eb = ecfg.encode_backend if ecfg.encode_backend != "auto" else auto
         if eb == "kernel" and page_elems % 4096:
             raise ValueError(
                 f"page holds {page_elems} elements — the grouped wire "
@@ -238,6 +239,8 @@ class Engine:
         wall = time.perf_counter() - t0
         total = sum(len(v) for v in tokens_out.values())
         metrics = {
+            "completed": float(sum(len(tokens_out[r.rid]) == r.max_new
+                                   for r in requests)),
             "wall_s": wall,
             "total_tokens": float(total),
             "tokens_per_s": total / wall if wall > 0 else 0.0,

@@ -28,7 +28,7 @@ def test_kernel_matches_ref_stochastic(shape, ilfl):
     bits = _bits(jax.random.fold_in(key, 1), shape)
     fmt3 = jnp.array([il, fl, 0], jnp.int32)
 
-    q_k, vec_k = dps_quant_pallas(x, fmt3, bits)
+    q_k, vec_k = dps_quant_pallas(x, fmt3, bits, interpret=True)
     q_r, vec_r = dps_quant_ref(x, il, fl, bits)
 
     np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_r))
@@ -43,7 +43,8 @@ def test_kernel_matches_ref_nearest(ilfl):
     x = jax.random.normal(key, (256, 1024)) * (2.0 ** (il - 2))
     bits = jnp.zeros((256, 1024), jnp.uint32)
     fmt3 = jnp.array([il, fl, 0], jnp.int32)
-    q_k, vec_k = dps_quant_pallas(x, fmt3, bits, stochastic=False)
+    q_k, vec_k = dps_quant_pallas(x, fmt3, bits, stochastic=False,
+                                   interpret=True)
     q_r, vec_r = dps_quant_ref(x, il, fl, bits, mode="nearest")
     np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_r))
     np.testing.assert_allclose(np.asarray(vec_k), np.asarray(vec_r),
@@ -56,7 +57,7 @@ def test_kernel_dtypes(dtype):
     x = (jax.random.normal(key, (64, 256)) * 4).astype(dtype)
     bits = _bits(jax.random.fold_in(key, 1), (64, 256))
     fmt3 = jnp.array([5, 6, 0], jnp.int32)
-    q_k, vec_k = dps_quant_pallas(x, fmt3, bits)
+    q_k, vec_k = dps_quant_pallas(x, fmt3, bits, interpret=True)
     q_r, vec_r = dps_quant_ref(x, 5, 6, bits)
     assert q_k.dtype == dtype
     np.testing.assert_array_equal(np.asarray(q_k, np.float32),
@@ -99,7 +100,8 @@ def test_kernel_dynamic_fmt_single_compile():
     key = jax.random.key(4)
     x = jax.random.normal(key, (256, 1024))
     bits = _bits(key, (256, 1024))
-    f = jax.jit(lambda x, fmt3, bits: dps_quant_pallas(x, fmt3, bits))
+    f = jax.jit(lambda x, fmt3, bits: dps_quant_pallas(x, fmt3, bits,
+                                                        interpret=True))
     q1, _ = f(x, jnp.array([4, 2, 0], jnp.int32), bits)
     q2, _ = f(x, jnp.array([8, 12, 0], jnp.int32), bits)
     # finer grid -> strictly smaller (or equal) error
@@ -120,7 +122,7 @@ def test_wire_kernel_matches_ref_stochastic(shape, ilfl):
     x = jax.random.normal(key, shape) * (2.0 ** (il - 1))
     bits = _bits(jax.random.fold_in(key, 1), shape)
     fmt3 = jnp.array([il, fl, 0], jnp.int32)
-    w_k, vec_k = dps_quant_wire_pallas(x, fmt3, bits)
+    w_k, vec_k = dps_quant_wire_pallas(x, fmt3, bits, interpret=True)
     w_r, vec_r = dps_quant_wire_ref(x, il, fl, bits)
     assert w_k.dtype == jnp.int8
     np.testing.assert_array_equal(np.asarray(w_k), np.asarray(w_r))
@@ -135,7 +137,7 @@ def test_wire_kernel_saturates_overwide_format_into_overflow():
     x = jax.random.normal(key, (256, 1024)) * 4.0   # y = x·2^8 well past 127
     bits = _bits(jax.random.fold_in(key, 1), (256, 1024))
     fmt3 = jnp.array([8, 8, 0], jnp.int32)
-    w_k, vec_k = dps_quant_wire_pallas(x, fmt3, bits)
+    w_k, vec_k = dps_quant_wire_pallas(x, fmt3, bits, interpret=True)
     w_r, vec_r = dps_quant_wire_ref(x, 8, 8, bits)
     np.testing.assert_array_equal(np.asarray(w_k), np.asarray(w_r))
     np.testing.assert_allclose(np.asarray(vec_k), np.asarray(vec_r),
@@ -169,7 +171,8 @@ def test_wire_kernel_dynamic_fmt_single_compile():
     key = jax.random.key(4)
     x = jax.random.normal(key, (256, 1024))
     bits = _bits(key, (256, 1024))
-    f = jax.jit(lambda x, fmt3, bits: dps_quant_wire_pallas(x, fmt3, bits))
+    f = jax.jit(lambda x, fmt3, bits: dps_quant_wire_pallas(x, fmt3, bits,
+                                                             interpret=True))
     w1, _ = f(x, jnp.array([3, 5, 0], jnp.int32), bits)
     w2, _ = f(x, jnp.array([2, 6, 0], jnp.int32), bits)
     assert f._cache_size() == 1          # one executable, two formats
@@ -237,7 +240,7 @@ def test_grouped_wire_kernel_matches_ref(tiles_spec, ilfl):
     for stochastic in (True, False):
         w_k, mat_k = dps_quant_group_wire_pallas(
             x, fmt_tab, tg, jnp.zeros((1,), jnp.int32), bits, mask,
-            stochastic=stochastic, quantum=Q)
+            stochastic=stochastic, quantum=Q, interpret=True)
         w_r, mat_r = dps_quant_group_wire_ref(
             x, jnp.array(il), jnp.array(fl), tg, bits, mask, Q,
             mode="stochastic" if stochastic else "nearest")
@@ -257,13 +260,14 @@ def test_grouped_wire_kernel_matches_global_kernel_per_group():
     fmt_tab = jnp.stack([jnp.array(il, jnp.int32),
                          jnp.array(fl, jnp.int32)], axis=1)
     w_g, mat_g = dps_quant_group_wire_pallas(
-        x, fmt_tab, tg, jnp.zeros((1,), jnp.int32), bits, mask, quantum=Q)
+        x, fmt_tab, tg, jnp.zeros((1,), jnp.int32), bits, mask, quantum=Q,
+        interpret=True)
     bounds = [(0, 2 * Q), (2 * Q, 3 * Q), (3 * Q, 4 * Q)]
     for g, (lo, hi) in enumerate(bounds):
         fmt3 = jnp.array([il[g], fl[g], 0], jnp.int32)
         w_i, vec_i = dps_quant_wire_pallas(
             np.asarray(x[lo:hi]).reshape(-1, 128), fmt3,
-            np.asarray(bits[lo:hi]).reshape(-1, 128))
+            np.asarray(bits[lo:hi]).reshape(-1, 128), interpret=True)
         np.testing.assert_array_equal(np.asarray(w_g[lo:hi]),
                                       np.asarray(w_i).reshape(-1))
         np.testing.assert_allclose(np.asarray(mat_g[g]), np.asarray(vec_i),
@@ -289,7 +293,7 @@ def test_wire_reduce_kernel_matches_ref_and_jnp_mean():
     fl = jnp.array([5, 2, 7], jnp.int32)
     tg = jnp.array([0, 2, 1], jnp.int32)
     fmt_tab = jnp.stack([jnp.array([3, 6, 1], jnp.int32), fl], axis=1)
-    out = dps_wire_reduce_pallas(wire, fmt_tab, tg, quantum=Q)
+    out = dps_wire_reduce_pallas(wire, fmt_tab, tg, quantum=Q, interpret=True)
     ref = dps_wire_reduce_ref(wire, fl, tg, Q)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # against the naive jnp decode-then-mean

@@ -104,17 +104,18 @@ def test_plan_validation_rejects_malformed():
 
 def test_bucketed_collective_bitexact_vs_monolithic():
     run_with_devices("""
-        import jax, repro.compat
+        import jax
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.core.fixed_point import FixedPointFormat
         from repro.dist import collectives, overlap
+        from repro.dist.sharding import make_mesh
 
         sizes = (48000, 1200, 30720, 120, 840, 10)
         plan = overlap.plan_buckets(sizes, 1 << 16)
         assert plan.n_buckets >= 2
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         tree = {f"l{i}": jax.random.normal(
                     jax.random.fold_in(jax.random.key(0), i), (s,)) * 0.5
                 for i, s in enumerate(sizes)}
@@ -171,15 +172,16 @@ def test_bucketed_collective_bitexact_vs_monolithic():
 def test_overlap_step_bitexact_and_flow_clean():
     run_with_devices("""
         import dataclasses
-        import jax, repro.compat
+        import jax
         import jax.numpy as jnp
         from repro.analysis import flow
         from repro.core import qtrain
         from repro.core.dps import DPSHyper
         from repro.models import lenet
         from repro.optim import SGDConfig, make_optimizer
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         base = dict(enabled=False, controller="static",
                     hyper_grads=DPSHyper(il_init=6, fl_init=2),
                     rounding="nearest", grad_allreduce_bits=8)
@@ -251,13 +253,14 @@ def test_bucketed_bitexact_both_modes():
     every rounding-bit draw (dispatch and gather leg) is keyed by global
     leaf index, so the bucket partition cannot move it."""
     run_with_devices("""
-        import jax, repro.compat
+        import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.core.fixed_point import FixedPointFormat
         from repro.dist import collectives, overlap
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         tree = {"a": jax.random.normal(jax.random.key(0), (8, 37, 5)) * .2,
                 "b": jax.random.normal(jax.random.key(1), (8, 3)) * .1,
                 "c": jax.random.normal(jax.random.key(2), (8, 300)) * .3,
@@ -290,13 +293,14 @@ def test_bucketed_bitexact_both_modes():
 
 def test_wire_overlap_without_bits_is_noop():
     run_with_devices("""
-        import jax, repro.compat
+        import jax
         import jax.numpy as jnp
         from repro.core import qtrain
         from repro.models import lenet
         from repro.optim import SGDConfig, make_optimizer
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         # wire_overlap without grad_allreduce_bits: no wire, no buckets —
         # the step must match the meshless reference bit-exactly
         qcfg = qtrain.QuantConfig(enabled=True, wire_overlap=True)

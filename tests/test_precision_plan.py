@@ -24,6 +24,7 @@ from repro.core import qtrain
 from repro.core.dps import (CONTROLLERS, DomainSpec, DpsBundle, DPSHyper,
                             PrecisionPlan, wire_hyper)
 from repro.core.fixed_point import QuantStats
+from repro.dist.sharding import make_mesh
 
 
 def random_plan(rng: random.Random, max_domains: int = 5) -> PrecisionPlan:
@@ -64,7 +65,7 @@ def random_stats(rng: random.Random, shape=()) -> QuantStats:
 
 def test_random_plans_roundtrip_jit_and_shard_map_as_pytrees():
     rng = random.Random(0)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     for trial in range(12):
         plan = random_plan(rng)
         bundle = plan.init()
@@ -200,7 +201,7 @@ def test_per_layer_wire_with_zero_opt_raises():
     qcfg = qtrain.QuantConfig(enabled=True, grad_allreduce_bits=8,
                               zero_opt_shards=1
                               ).with_per_layer_wire(params)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     opt = make_optimizer(SGDConfig())
     # single-device mesh: neither path engages, so the build succeeds ...
     qtrain.make_train_step(lenet.loss_fn, opt, qcfg, mesh=mesh)
@@ -210,7 +211,7 @@ def test_per_layer_wire_with_zero_opt_raises():
     # a 1-axis mesh of the real device count when >1 devices exist.
     if jax.device_count() > 1:
         n = jax.device_count()
-        mesh_n = jax.make_mesh((n,), ("data",))
+        mesh_n = make_mesh((n,), ("data",))
         qcfg_n = qtrain.QuantConfig(enabled=True, grad_allreduce_bits=8,
                                     zero_opt_shards=n
                                     ).with_per_layer_wire(params)

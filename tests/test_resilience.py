@@ -76,8 +76,9 @@ def test_guards_transparent_across_rounding_modes():
         from repro.models import lenet
         from repro.optim import SGDConfig, make_optimizer
         from repro.resilience import GuardConfig
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         opt = make_optimizer(SGDConfig())
         params = lenet.init(jax.random.key(0))
         batch = {"images": jax.random.normal(jax.random.key(2),
@@ -131,8 +132,9 @@ def test_nan_fault_skip_degrade_rearm():
         from repro.optim import SGDConfig, make_optimizer
         from repro.resilience import (FaultPlan, GuardConfig,
                                       HEALTH_GRADS_NONFINITE, HEALTH_SKIPPED)
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         opt = make_optimizer(SGDConfig())
         params = lenet.init(jax.random.key(0))
         batch = {"images": jax.random.normal(jax.random.key(2),
@@ -181,8 +183,9 @@ def test_overflow_storm_degrade_and_recover():
         from repro.optim import SGDConfig, make_optimizer
         from repro.resilience import (FaultPlan, GuardConfig,
                                       HEALTH_OVERFLOW_STORM)
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         opt = make_optimizer(SGDConfig())
         params = lenet.init(jax.random.key(0))
         batch = {"images": jax.random.normal(jax.random.key(2),
@@ -237,8 +240,9 @@ def test_wire_bitflip_spike_detected_and_skipped():
         from repro.optim import SGDConfig, make_optimizer
         from repro.resilience import (FaultPlan, GuardConfig,
                                       HEALTH_GRAD_SPIKE, HEALTH_SKIPPED)
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         opt = make_optimizer(SGDConfig())
         params = lenet.init(jax.random.key(0))
         batch = {"images": jax.random.normal(jax.random.key(2),
@@ -472,10 +476,11 @@ def _taint_jaxpr(make_signal):
     from jax.sharding import PartitionSpec as P
     from repro.core.fixed_point import FixedPointFormat
     from repro.dist import collectives
+    from repro.dist.sharding import make_mesh
 
     fmt = FixedPointFormat.create(3, 5)
     tree = {"leaf0": jnp.ones((64,), jnp.float32)}
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def body(tr, k):
         mean, stats = collectives.dps_allreduce_mean_tree(tr, fmt, "data", k)

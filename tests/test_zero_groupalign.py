@@ -146,11 +146,12 @@ def test_groupaligned_rejects_malformed_buckets():
 
 _PARITY_PRELUDE = """
     import warnings
-    import jax, repro.compat
+    import jax
     import jax.numpy as jnp
     from repro.core import qtrain
     from repro.models.common import rms_norm
     from repro.optim import SGDConfig, make_optimizer
+    from repro.dist.sharding import make_mesh
 
     def loss_fn(params, batch, qctx=None):
         h = rms_norm(batch["x"] @ params["w1"], params["norm_scale"])
@@ -164,7 +165,7 @@ _PARITY_PRELUDE = """
               "w2": jax.random.normal(jax.random.key(4), (37, 8)) * 0.3}
     batch = {"x": jax.random.normal(jax.random.key(1), (32, 16)),
              "y": jax.random.normal(jax.random.key(2), (32, 8))}
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     # power-of-two hypers: shard-local SGD math is FMA-contraction-proof
     opt = make_optimizer(SGDConfig(lr=0.0078125, momentum=0.5,
                                    weight_decay=0.00048828125,
@@ -248,13 +249,14 @@ def test_zero_shards_mismatch_warns_and_falls_back():
     warns and runs the replicated optimizer state (no raise)."""
     run_with_devices("""
         import warnings
-        import jax, repro.compat
+        import jax
         import jax.numpy as jnp
         from repro.core import qtrain
         from repro.models import lenet
         from repro.optim import SGDConfig, make_optimizer
+        from repro.dist.sharding import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         qcfg = qtrain.QuantConfig(enabled=True, zero_opt_shards=4)
         assert not qtrain.zero_opt_engaged(qcfg, mesh)
         opt = make_optimizer(SGDConfig())
@@ -279,7 +281,7 @@ def test_zero_groupalign_opt_state_layout_matches_step():
     """zero_opt_state(qcfg=...) sizes the flat state for the SAME layout
     the step shards over — the aligned padded size, not the plain one."""
     run_with_devices("""
-        import jax, repro.compat
+        import jax
         import jax.numpy as jnp
         from repro.core import qtrain
         from repro.dist.sharding import GroupAlignedPartitioner, \\
