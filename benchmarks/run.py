@@ -1,12 +1,12 @@
-"""Benchmark aggregator: one bench per paper artifact + the roofline table.
+"""Benchmark aggregator: one bench per paper artifact.
 
   PYTHONPATH=src python -m benchmarks.run            # quick mode
   BENCH_QUICK=0 PYTHONPATH=src python -m benchmarks.run   # paper-scale
 
 The MNIST-class benches reproduce the paper's own evaluation (Figs. 3-4,
 Table 1, the Gupta rounding comparison); bench_quant covers the kernel
-hot-spot; the roofline table is derived from results/dryrun/ (run
-``python -m repro.launch.dryrun --all --mesh both`` first for all cells).
+hot-spot.  Device timings and roofline shares come from ``bench/run.py``
+on the chip, not from here.
 """
 
 from __future__ import annotations
@@ -20,7 +20,7 @@ import traceback
 def main():
     from benchmarks import (bench_bitwidths, bench_collectives,
                             bench_convergence, bench_quant, bench_rounding,
-                            bench_schemes, bench_zero, roofline)
+                            bench_schemes, bench_zero)
     suites = [
         ("convergence (paper Fig. 4)", bench_convergence.run),
         ("bitwidths (paper Fig. 3)", bench_bitwidths.run),
@@ -29,7 +29,6 @@ def main():
         ("quantizer hot-spot", bench_quant.run),
         ("collectives (int8 gradient wire)", bench_collectives.run),
         ("ZeRO-1 (sharded optimizer + int8 wire)", bench_zero.run),
-        ("roofline (dry-run artifacts)", roofline.run),
     ]
     failures = []
     for name, fn in suites:
@@ -42,8 +41,6 @@ def main():
                 print(json.dumps(claims, indent=1))
                 if not all(claims.values()):
                     failures.append((name, claims))
-            if name.startswith("roofline"):
-                print(roofline.table(out["cells"]))
             print(f"  ({time.time() - t0:.1f}s)", flush=True)
         except Exception:
             traceback.print_exc()

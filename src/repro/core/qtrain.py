@@ -39,6 +39,13 @@ pinned — now as a stability guarantee — by
 Everything here is shape-polymorphic and mesh-agnostic: stats are plain
 ``jnp`` reductions, so under ``pjit`` they come out globally reduced, and the
 ⟨IL, FL⟩ state is replicated.
+
+The step's passes carry ``jax.named_scope`` names, which reach the compiled
+program's ``op_name`` metadata and change nothing else, so a profile can
+attribute device time to them: ``dps.weights`` (the weight snap and
+re-snap), ``dps.acts`` (the forward taps), ``dps.grads`` (the backward taps
+and the optimizer-input gradient quantization) and ``optim`` (the optimizer
+update and its apply).
 """
 
 from __future__ import annotations
@@ -307,7 +314,9 @@ from functools import partial
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _qtap(mode, x, a_fmt, g_fmt, kf, kb):
-    q, stats = fxp.quantize(x, a_fmt, mode=mode, key=kf, compute_stats=True)
+    with jax.named_scope("dps.acts"):
+        q, stats = fxp.quantize(x, a_fmt, mode=mode, key=kf,
+                                compute_stats=True)
     return q, stats
 
 
@@ -318,7 +327,9 @@ def _qtap_fwd(mode, x, a_fmt, g_fmt, kf, kb):
 
 def _qtap_bwd(mode, res, cot):
     g_fmt, kb = res
-    gq, _ = fxp.quantize(cot[0], g_fmt, mode=mode, key=kb, compute_stats=False)
+    with jax.named_scope("dps.grads"):
+        gq, _ = fxp.quantize(cot[0], g_fmt, mode=mode, key=kb,
+                             compute_stats=False)
     return (gq, None, None, None, None)
 
 
@@ -333,16 +344,18 @@ def quantize_params(params, fmt: FixedPointFormat, qcfg: QuantConfig, key):
     """Snap the parameter tree to the weight grid. Returns (qparams, stats)."""
     if not qcfg.enabled or not qcfg.policy.quantizes("weights"):
         return params, QuantStats.zero()
-    return fxp.quantize_tree(params, fmt, mode=qcfg.rounding, key=key,
-                             predicate=qcfg.policy.param_predicate())
+    with jax.named_scope("dps.weights"):
+        return fxp.quantize_tree(params, fmt, mode=qcfg.rounding, key=key,
+                                 predicate=qcfg.policy.param_predicate())
 
 
 def quantize_grads(grads, fmt: FixedPointFormat, qcfg: QuantConfig, key):
     """Quantize parameter gradients before the optimizer step."""
     if not qcfg.enabled or not qcfg.policy.quantizes("grads"):
         return grads, QuantStats.zero()
-    return fxp.quantize_tree(grads, fmt, mode=qcfg.rounding, key=key,
-                             predicate=qcfg.policy.param_predicate())
+    with jax.named_scope("dps.grads"):
+        return fxp.quantize_tree(grads, fmt, mode=qcfg.rounding, key=key,
+                                 predicate=qcfg.policy.param_predicate())
 
 
 # ---------------------------------------------------------------------------
@@ -1205,8 +1218,9 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params,
                                                   count=state.step)
-            new_params = jax.tree.map(lambda p, u: p + u, state.params,
-                                      updates)
+            with jax.named_scope("optim"):
+                new_params = jax.tree.map(lambda p, u: p + u, state.params,
+                                          updates)
 
         if "dlogits_stats" in aux and qcfg.stat_scope == "last_layer":
             g_stats = aux["dlogits_stats"]

@@ -11,6 +11,9 @@ rounding** on the state update (``state_dtype="bfloat16"``).  This is the
 paper's own Gupta-et-al. insight applied to the optimizer — tiny moment
 updates survive in expectation — and halves optimizer HBM, which is what
 lets the 340B config fit a single 256-chip pod (see DESIGN §5).
+
+Every ``update`` and ``update_shard`` runs under the named scope ``optim``,
+so a profile of the compiled step can attribute the optimizer's device time.
 """
 
 from __future__ import annotations
@@ -163,23 +166,24 @@ class SGD:
         return (-lr * mu_new).astype(p.dtype), _sr_cast(mu_new, dt, k)
 
     def update(self, grads, state, params, count):
-        cfg = self.cfg
-        if cfg.clip_norm:
-            grads, _ = _clip_by_norm(grads, cfg.clip_norm)
-        lr = self.sched(count)
-        dt = self._state_dtype()
-        key = jax.random.fold_in(jax.random.key(17), count)
-        leaves, treedef = jax.tree_util.tree_flatten(state["mu"])
-        keys = jax.random.split(key, len(leaves))
-        keys = jax.tree_util.tree_unflatten(treedef, list(keys))
+        with jax.named_scope("optim"):
+            cfg = self.cfg
+            if cfg.clip_norm:
+                grads, _ = _clip_by_norm(grads, cfg.clip_norm)
+            lr = self.sched(count)
+            dt = self._state_dtype()
+            key = jax.random.fold_in(jax.random.key(17), count)
+            leaves, treedef = jax.tree_util.tree_flatten(state["mu"])
+            keys = jax.random.split(key, len(leaves))
+            keys = jax.tree_util.tree_unflatten(treedef, list(keys))
 
-        one = lambda g, mu, p, k: self._leaf(lr, dt, g, mu, p, k)
-        out = jax.tree.map(one, grads, state["mu"], params, keys)
-        updates = jax.tree.map(lambda t: t[0], out,
-                               is_leaf=lambda x: isinstance(x, tuple))
-        mu = jax.tree.map(lambda t: t[1], out,
-                          is_leaf=lambda x: isinstance(x, tuple))
-        return updates, {"mu": mu}
+            one = lambda g, mu, p, k: self._leaf(lr, dt, g, mu, p, k)
+            out = jax.tree.map(one, grads, state["mu"], params, keys)
+            updates = jax.tree.map(lambda t: t[0], out,
+                                   is_leaf=lambda x: isinstance(x, tuple))
+            mu = jax.tree.map(lambda t: t[1], out,
+                              is_leaf=lambda x: isinstance(x, tuple))
+            return updates, {"mu": mu}
 
     # --- ZeRO-1 shard-local interface (see repro.dist.sharding) ---
 
@@ -204,13 +208,15 @@ class SGD:
         per-leaf :func:`_global_norm`, so the clip scale (and hence the
         update) may differ from the replicated step in the last ULP.
         """
-        cfg = self.cfg
-        if cfg.clip_norm:
-            grads, _ = _clip_by_norm_shard(grads, cfg.clip_norm, axis_name)
-        upd, mu = self._leaf(self.sched(count), self._state_dtype(),
-                             grads, state["mu"], params,
-                             _shard_key(17, count, axis_name))
-        return upd, {"mu": mu}
+        with jax.named_scope("optim"):
+            cfg = self.cfg
+            if cfg.clip_norm:
+                grads, _ = _clip_by_norm_shard(grads, cfg.clip_norm,
+                                               axis_name)
+            upd, mu = self._leaf(self.sched(count), self._state_dtype(),
+                                 grads, state["mu"], params,
+                                 _shard_key(17, count, axis_name))
+            return upd, {"mu": mu}
 
 
 class AdamW:
@@ -249,22 +255,25 @@ class AdamW:
                 _sr_cast(m_new, dt, k1), _sr_cast(v_new, dt, k2))
 
     def update(self, grads, state, params, count):
-        cfg = self.cfg
-        if cfg.clip_norm:
-            grads, _ = _clip_by_norm(grads, cfg.clip_norm)
-        lr = self.sched(count)
-        bc1, bc2 = self._bias_corrections(count)
-        dt = self._state_dtype()
-        key = jax.random.fold_in(jax.random.key(23), count)
-        leaves, treedef = jax.tree_util.tree_flatten(state["m"])
-        keys = jax.random.split(key, len(leaves))
-        keys = jax.tree_util.tree_unflatten(treedef, list(keys))
+        with jax.named_scope("optim"):
+            cfg = self.cfg
+            if cfg.clip_norm:
+                grads, _ = _clip_by_norm(grads, cfg.clip_norm)
+            lr = self.sched(count)
+            bc1, bc2 = self._bias_corrections(count)
+            dt = self._state_dtype()
+            key = jax.random.fold_in(jax.random.key(23), count)
+            leaves, treedef = jax.tree_util.tree_flatten(state["m"])
+            keys = jax.random.split(key, len(leaves))
+            keys = jax.tree_util.tree_unflatten(treedef, list(keys))
 
-        one = lambda g, m, v, p, k: self._leaf(lr, bc1, bc2, dt, g, m, v, p, k)
-        out = jax.tree.map(one, grads, state["m"], state["v"], params, keys)
-        pick = lambda i: jax.tree.map(lambda t: t[i], out,
-                                      is_leaf=lambda x: isinstance(x, tuple))
-        return pick(0), {"m": pick(1), "v": pick(2)}
+            one = lambda g, m, v, p, k: self._leaf(lr, bc1, bc2, dt, g, m, v,
+                                                   p, k)
+            out = jax.tree.map(one, grads, state["m"], state["v"], params,
+                               keys)
+            pick = lambda i: jax.tree.map(
+                lambda t: t[i], out, is_leaf=lambda x: isinstance(x, tuple))
+            return pick(0), {"m": pick(1), "v": pick(2)}
 
     # --- ZeRO-1 shard-local interface (see repro.dist.sharding) ---
 
@@ -286,15 +295,17 @@ class AdamW:
         Same element-wise math as :meth:`update`; ``clip_norm`` uses the
         cross-shard global norm (psum over ``axis_name``).
         """
-        cfg = self.cfg
-        if cfg.clip_norm:
-            grads, _ = _clip_by_norm_shard(grads, cfg.clip_norm, axis_name)
-        bc1, bc2 = self._bias_corrections(count)
-        upd, m, v = self._leaf(self.sched(count), bc1, bc2,
-                               self._state_dtype(), grads, state["m"],
-                               state["v"], params,
-                               _shard_key(23, count, axis_name))
-        return upd, {"m": m, "v": v}
+        with jax.named_scope("optim"):
+            cfg = self.cfg
+            if cfg.clip_norm:
+                grads, _ = _clip_by_norm_shard(grads, cfg.clip_norm,
+                                               axis_name)
+            bc1, bc2 = self._bias_corrections(count)
+            upd, m, v = self._leaf(self.sched(count), bc1, bc2,
+                                   self._state_dtype(), grads, state["m"],
+                                   state["v"], params,
+                                   _shard_key(23, count, axis_name))
+            return upd, {"m": m, "v": v}
 
 
 def make_optimizer(cfg):
