@@ -164,6 +164,11 @@ def kernels_phase(small: bool):
             out.append(f"{'wire' if wire else 'quant'}/prng mean err "
                        f"{err:+.1e} steps")
 
+    # the train step's tree passes: the fused leaf kernel over the leaves
+    # of the benchmark's one-layer InternVL2-26B language model, against
+    # the jnp path: unbiased, and the same count, overflow and max
+    out.append(tree_quant_check(small, next(keys)))
+
     # grouped wire: one layer's attention weights, one format per leaf
     sizes = ((4096, 8192, 8192, 12288) if small else
              (D_MODEL * D_MODEL, D_MODEL * N_KV * HEAD_DIM,
@@ -224,6 +229,64 @@ def kernels_phase(small: bool):
     _check(rel <= ATTN_RTOL, f"paged attention: rel diff {rel:.2e}")
     out.append(f"paged_attn rel {rel:.1e}")
     return "; ".join(out)
+
+
+def tree_quant_check(small: bool, key):
+    """``quantize_tree`` with the fused leaf kernel (on a chip: the on-chip
+    PRNG) against the jnp path, at the leaf shapes of the benchmark's
+    ``internvl2-26b-l1`` (bf16 weights, vocab padded to 11776; the norms
+    are left out by the policy).  ``small`` cuts every width by 32."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import fixed_point as fxp
+    from repro.core.policy import QuantPolicy
+    from repro.kernels import ops
+
+    d, ff, kv, vocab = (6144, 16384, 1024, 11776)
+    if small:
+        d, ff, kv, vocab = d // 32, ff // 32, kv // 32, vocab // 32
+    shapes = {"embed/tok": (vocab, d), "embed/unembed": (d, vocab),
+              "attn/wk": (1, d, kv), "attn/wo": (1, d, d),
+              "attn/wq": (1, d, d), "attn/wv": (1, d, kv),
+              "mlp/w_gate": (1, d, ff), "mlp/w_in": (1, d, ff),
+              "mlp/w_out": (1, ff, d), "norm1": (1, d)}
+    ks = jax.random.split(key, len(shapes) + 1)
+    tree = {name: (jax.random.normal(k, s) * 0.02).astype(
+                jnp.float32 if name.startswith("norm") else jnp.bfloat16)
+            for k, (name, s) in zip(ks, shapes.items())}
+    il, fl = 2, 12
+    fmt = fxp.FixedPointFormat.create(il, fl)
+    step = 2.0 ** -fl
+    lo, hi = -(2.0 ** (il - 1)), 2.0 ** (il - 1) - step
+    pred = QuantPolicy().param_predicate()
+
+    def snap(t, quantize_fn):
+        return fxp.quantize_tree(t, fmt, key=ks[-1], predicate=pred,
+                                 quantize_fn=quantize_fn)
+
+    q_k, s_k = jax.jit(functools.partial(
+        snap, quantize_fn=ops.dps_quantize_leaf))(tree)
+    _, s_j = jax.jit(functools.partial(snap, quantize_fn=fxp.quantize))(tree)
+    weights = [n for n in shapes if n != "norm1"]
+    _check(float(s_k.count) == sum(tree[n].size for n in weights)
+           and bool(jnp.array_equal(q_k["norm1"], tree["norm1"])),
+           "tree quant: the policy's leaves were not the ones snapped")
+    err = sum(float(jnp.sum(q_k[n].astype(jnp.float32)
+                            - jnp.clip(tree[n].astype(jnp.float32), lo, hi)))
+              for n in weights) / (float(s_k.count) * step)
+    _check(abs(err) <= PRNG_MEAN_ERR_STEPS,
+           f"tree quant: mean rounding error {err:.2e} steps")
+    for f in ("count", "nonzero", "overflow", "max_abs"):
+        a, b = float(getattr(s_k, f)), float(getattr(s_j, f))
+        _check(a == b, f"tree quant stat {f}: {a} vs {b}")
+    rel = abs(float(s_k.abs_err_sum) / float(s_j.abs_err_sum) - 1.0)
+    _check(rel <= PRNG_ABS_ERR_RTOL,
+           f"tree quant: abs-err sum differs by {rel:.2e}")
+    return (f"tree/{'bits' if small else 'prng'} mean err {err:+.1e} steps "
+            f"over {int(s_k.count)} weights")
 
 
 # ---------------------------------------------------------------------------

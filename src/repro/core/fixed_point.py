@@ -295,12 +295,17 @@ def wire_quantize(
 
 
 def quantize_tree(tree, fmt: FixedPointFormat, *, mode: str = ROUND_STOCHASTIC,
-                  key: Optional[jax.Array] = None, predicate=None):
+                  key: Optional[jax.Array] = None, predicate=None,
+                  quantize_fn=quantize):
     """Quantize every leaf of a pytree with one shared format.
 
     ``predicate(path, leaf) -> bool`` selects which leaves are quantized
     (see ``repro.core.policy``).  Returns ``(tree_q, merged QuantStats)``.
     Per-leaf RNG derives from ``key`` by leaf index (stable ordering).
+    ``quantize_fn(x, fmt, mode=, key=) -> (q, QuantStats)`` quantizes one
+    leaf, or one layer of a stacked leaf: :func:`quantize` by default, the
+    fused kernel ``repro.kernels.ops.dps_quantize_leaf`` in a TPU train
+    step.
 
     Leaves are SERIALIZED with ``optimization_barrier``: each quantization
     event's temporaries (the u32 random-bits tensor + fp32 working copies,
@@ -320,14 +325,15 @@ def quantize_tree(tree, fmt: FixedPointFormat, *, mode: str = ROUND_STOCHASTIC,
             continue
         leaf_d, _ = jax.lax.optimization_barrier((leaf, dep))
         k = jax.random.fold_in(key, i) if key is not None else None
-        q, s = _quantize_leaf(leaf_d, fmt, mode, k)
+        q, s = _quantize_leaf(leaf_d, fmt, mode, k, quantize_fn)
         out.append(q)
         stats = stats.merge(s)
         dep = s.count
     return jax.tree_util.tree_unflatten(treedef, [v for v in out]), stats
 
 
-def _quantize_leaf(leaf: jax.Array, fmt: FixedPointFormat, mode: str, key):
+def _quantize_leaf(leaf: jax.Array, fmt: FixedPointFormat, mode: str, key,
+                   quantize_fn):
     """Quantize one tree leaf with bounded temporaries.
 
     Layer-stacked weights (ndim ≥ 3, leading dim = layers, never sharded)
@@ -344,8 +350,8 @@ def _quantize_leaf(leaf: jax.Array, fmt: FixedPointFormat, mode: str, key):
 
         def body(xs):
             sl, k = xs
-            return quantize(sl, fmt, mode=mode,
-                            key=k if key is not None else None)
+            return quantize_fn(sl, fmt, mode=mode,
+                               key=k if key is not None else None)
 
         q, s = jax.lax.map(body, (leaf, keys))
         return q, QuantStats(
@@ -353,4 +359,4 @@ def _quantize_leaf(leaf: jax.Array, fmt: FixedPointFormat, mode: str, key):
             overflow=jnp.sum(s.overflow), abs_err_sum=jnp.sum(s.abs_err_sum),
             rel_err_sum=jnp.sum(s.rel_err_sum), abs_sum=jnp.sum(s.abs_sum),
             max_abs=jnp.max(s.max_abs))
-    return quantize(leaf, fmt, mode=mode, key=key)
+    return quantize_fn(leaf, fmt, mode=mode, key=key)

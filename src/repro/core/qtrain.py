@@ -64,6 +64,8 @@ from repro.core import fixed_point as fxp
 from repro.core.dps import DpsBundle, DomainSpec, PrecisionPlan
 from repro.core.fixed_point import FixedPointFormat, QuantStats
 from repro.core.policy import QuantPolicy
+from repro.device import on_tpu
+from repro.kernels import ops as kernel_ops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -340,22 +342,35 @@ _qtap.defvjp(_qtap_fwd, _qtap_bwd)
 # Weight / gradient tree quantization.
 # ---------------------------------------------------------------------------
 
-def quantize_params(params, fmt: FixedPointFormat, qcfg: QuantConfig, key):
-    """Snap the parameter tree to the weight grid. Returns (qparams, stats)."""
+def _leaf_quantizer(fused: bool):
+    """The per-leaf quantizer of the tree passes: the fused Pallas kernel
+    (on-chip PRNG, one ``pallas_call`` per leaf) or the jnp path."""
+    return kernel_ops.dps_quantize_leaf if fused else fxp.quantize
+
+
+def quantize_params(params, fmt: FixedPointFormat, qcfg: QuantConfig, key,
+                    fused: bool = False):
+    """Snap the parameter tree to the weight grid. Returns (qparams, stats).
+
+    ``fused`` quantizes each leaf with the fused kernel (see
+    ``make_train_step``'s ``fused_quant_active``)."""
     if not qcfg.enabled or not qcfg.policy.quantizes("weights"):
         return params, QuantStats.zero()
     with jax.named_scope("dps.weights"):
         return fxp.quantize_tree(params, fmt, mode=qcfg.rounding, key=key,
-                                 predicate=qcfg.policy.param_predicate())
+                                 predicate=qcfg.policy.param_predicate(),
+                                 quantize_fn=_leaf_quantizer(fused))
 
 
-def quantize_grads(grads, fmt: FixedPointFormat, qcfg: QuantConfig, key):
+def quantize_grads(grads, fmt: FixedPointFormat, qcfg: QuantConfig, key,
+                   fused: bool = False):
     """Quantize parameter gradients before the optimizer step."""
     if not qcfg.enabled or not qcfg.policy.quantizes("grads"):
         return grads, QuantStats.zero()
     with jax.named_scope("dps.grads"):
         return fxp.quantize_tree(grads, fmt, mode=qcfg.rounding, key=key,
-                                 predicate=qcfg.policy.param_predicate())
+                                 predicate=qcfg.policy.param_predicate(),
+                                 quantize_fn=_leaf_quantizer(fused))
 
 
 # ---------------------------------------------------------------------------
@@ -583,6 +598,13 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
     ``repro.dist.overlap``).  Mismatched ``zero_opt_shards`` vs the mesh
     warns and falls back to the replicated state — the same policy as
     every other engagement mismatch; only impossible configs raise.
+
+    On a TPU with no mesh or a one-device mesh the weight snaps and the
+    optimizer-input gradient quantization run the fused quantize kernel,
+    one ``pallas_call`` per quantized leaf, its rounding bits from the
+    core's PRNG (``train_step.fused_quant_active``); everywhere else they
+    run the jnp path.  Both round by Eq. (2) on the same grid and feed the
+    controllers the same stats; only the bit source differs.
     """
     plan = qcfg.plan()
     rounding = getattr(plan.controller("weights"), "rounding", qcfg.rounding)
@@ -599,6 +621,10 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
                          "is int8, so only 2..8 grid bits are supported")
     axis_sizes = _mesh_axis_sizes(mesh)
     n_data = int(axis_sizes.get(data_axis, 1))
+    # Off TPU the interpreter's PRNG returns zeros (stochastic rounding
+    # would floor), and under a multi-device mesh a pallas_call on a
+    # sharded leaf would gather it: those steps keep the jnp tree passes.
+    fused_quant = on_tpu() and (mesh is None or mesh.devices.size == 1)
     wire_sync = wire_bits is not None and n_data > 1
     if wire_sync and any(s > 1 for a, s in axis_sizes.items()
                          if a != data_axis):
@@ -1114,7 +1140,8 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
         fmts = bundle_formats(qcfg, state.dps)
 
         # -- forward/backward in the quantized regime (Alg. 1 lines 9-20) --
-        qparams, w_stats = quantize_params(state.params, fmts["weights"], qcfg, k_w)
+        qparams, w_stats = quantize_params(state.params, fmts["weights"],
+                                           qcfg, k_w, fused_quant)
         g_wire = p_wire = wire_stats = None
         bad_count = gnorm = None
         deg_g = deg_p = jnp.zeros((), jnp.int32)
@@ -1213,7 +1240,7 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
                     bad_count = rsl.nonfinite_count(grads)
                     gnorm = rsl.global_norm(grads)
                 grads, g_stats = quantize_grads(grads, fmts[grad_domain],
-                                                qcfg, k_g)
+                                                qcfg, k_g, fused_quant)
             # -- update (Alg. 1 line 18) --
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params,
@@ -1235,7 +1262,8 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
         if (qcfg.enabled and qcfg.policy.quantizes("weights")
                 and not qcfg.master_weights):
             new_params, w_stats2 = quantize_params(
-                new_params, fmts["weights"], qcfg, jax.random.fold_in(k_w, 1))
+                new_params, fmts["weights"], qcfg, jax.random.fold_in(k_w, 1),
+                fused_quant)
             w_stats = w_stats.merge(w_stats2)
 
         # -- scale_precision (Alg. 2, one controller per domain) --
@@ -1323,10 +1351,12 @@ def make_train_step(loss_fn, optimizer, qcfg: QuantConfig,
             guard=new_guard)
         return new_state, metrics
 
-    # introspection for drivers/tests: did the compressed paths engage?
+    # introspection for callers and tests: did the compressed paths and the
+    # fused tree quantization engage?
     train_step.wire_sync_active = wire_sync
     train_step.zero_opt_active = zero_opt
     train_step.wire_overlap_active = wire_overlap
     train_step.zero_groupaligned_active = zero_aligned
     train_step.guards_active = guards_on
+    train_step.fused_quant_active = fused_quant
     return train_step

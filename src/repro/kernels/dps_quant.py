@@ -22,6 +22,14 @@ Two tensor-output flavours share one kernel body:
     input tile, so the wire payload costs one read-x/write-wire pass and
     never exists as an fp32 intermediate in HBM.
 
+The train step's weight and gradient tree passes run a third flavour,
+``dps_quant_leaf_pallas``: one launch per tree leaf on the leaf's own
+buffer (leading dims folded into rows, the minor dim kept, so the fold is
+a bitcast), ragged edges masked in the kernel instead of padded, the
+rounding bits from the on-chip PRNG, and the stats accumulated in VMEM
+vectors and reduced once.  Its HBM traffic is read x, write q: nothing
+else.
+
 Two more kernels give the **per-group** wire pipeline the same one-pass
 traffic profile (see ``repro.dist.collectives`` for the layout contract):
 
@@ -67,9 +75,9 @@ Two variants of the stochastic-rounding noise source:
     what the test sweep asserts.
   * ``use_onchip_prng=True`` (TPU fast path): bits come from the per-core
     hardware PRNG (``pltpu.prng_seed``/``prng_random_bits``), halving HBM
-    reads.  This container's interpreter cannot execute the PRNG primitive
-    (verified: returns zeros), so this path is lowering-validated only and
-    is selected by ``ops.dps_quantize(..., onchip_prng=True)`` on real TPUs.
+    reads.  The Pallas interpreter cannot execute the PRNG primitive (it
+    returns zeros), so off a TPU this path is lowering-validated only; on
+    a TPU the train step's tree passes take it (``ops.dps_quantize_leaf``).
 
 ⟨IL, FL⟩ arrive as an SMEM scalar-prefetch operand, so precision changes at
 every training step re-use the same compiled kernel.
@@ -362,6 +370,186 @@ def dps_quant_wire_pallas(x: jax.Array, fmt3: jax.Array, bits: jax.Array,
     return _pallas_quant(x, fmt3, bits, mask, stochastic=stochastic,
                          use_onchip_prng=use_onchip_prng, block=block,
                          interpret=interpret, emit_wire=True)
+
+
+# ---------------------------------------------------------------------------
+# Tree-leaf kernel: the weight and gradient snaps of the train step.
+# ---------------------------------------------------------------------------
+
+# HBM bytes per grid block and lanes per block of the leaf kernel: 4 MiB,
+# a 1024 x 1024 block of bf16 in and out, double-buffered 8 MiB of VMEM.
+# Measured on a v5e at the benchmark's bf16 leaves, blocks of 2^18
+# elements ran at ~510 GB/s and of 2^20 at ~610, beside 630 GB/s for a
+# plain copy.  The body works through a block in unrolled strips of 32
+# rows, so its f32 temporaries stay a few dozen vregs whatever the block
+# (at 2^18-element blocks: unrolled strips ~510 GB/s, a fori_loop over
+# them ~370, the math on the whole block at once ~470).
+LEAF_BLOCK_BYTES = 4 << 20
+LEAF_LANES = 1024
+# block rows are a multiple of this: the int8 tile's 32 sublanes, which
+# also covers the f32 (8) and bf16 (16) tiles
+_LEAF_ROW_UNIT = 32
+
+
+def _leaf_dim(n: int, unit: int, cap: int) -> int:
+    """Block length along a dim of ``n``: all of it when ``n < unit``,
+    else a multiple of ``unit`` of at most ``cap`` that divides ``n``,
+    where one of at least ``cap / 8`` does, else the largest multiple (the
+    grid's last block then hangs past the edge, masked in the kernel)."""
+    if n < unit:
+        return n
+    top = min(cap, n) // unit * unit
+    for b in range(top, max(unit, top // 8) - 1, -unit):
+        if n % b == 0:
+            return b
+    return top
+
+
+def _leaf_block(rows: int, cols: int, elem_bytes: int):
+    """(bm, bn) grid block of the leaf kernel on a ``[rows, cols]`` leaf
+    whose elements move ``elem_bytes`` bytes each (x in, q out, and the
+    bits on the portable path)."""
+    bn = _leaf_dim(cols, 128, LEAF_LANES)
+    cap = max(_LEAF_ROW_UNIT, LEAF_BLOCK_BYTES // elem_bytes // bn
+              // _LEAF_ROW_UNIT * _LEAF_ROW_UNIT)
+    return _leaf_dim(rows, _LEAF_ROW_UNIT, cap), bn
+
+
+def _leaf_kernel(fmt_ref,        # SMEM: (3,) int32 [il, fl, seed]
+                 x_ref,          # VMEM: (bm, bn) block of the leaf
+                 *refs,          # [bits (bm, bn) uint32,] q out, stats
+                                 # out (N_STATS,) SMEM, acc scratch
+                 stochastic: bool, use_onchip_prng: bool, shape,
+                 ragged: bool, strip: int):
+    if stochastic and not use_onchip_prng:
+        bits_ref, q_ref, stats_ref, acc_ref = refs
+    else:
+        bits_ref = None
+        q_ref, stats_ref, acc_ref = refs
+    i, j = pl.program_id(0), pl.program_id(1)
+    last = ((i == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
+    bm, bn = x_ref.shape
+    scale = _exp2i(fmt_ref[1])
+    inv_scale = _exp2i(-fmt_ref[1])
+    span = _exp2i(fmt_ref[0] - 1 + fmt_ref[1])
+    qmax = span - 1.0
+    qmin = -span
+
+    @pl.when((i == 0) & (j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    if stochastic and use_onchip_prng:
+        # one stream per grid block, as in _kernel
+        pltpu.prng_seed(fmt_ref[2] + i * pl.num_programs(1) + j)
+
+    def body(r, acc):
+        x = x_ref[pl.ds(r, strip), :].astype(jnp.float32)
+        if ragged:
+            # lanes past the leaf's edge read as zero, which no stat counts
+            # (count is the leaf's static size)
+            rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+            ok = ((rows < shape[0] - i * bm - r)
+                  & (cols < shape[1] - j * bn))
+            x = jnp.where(ok, x, 0.0)
+        y = x * scale
+        yc = jnp.clip(y, qmin, qmax)
+        if stochastic:
+            bits = (pltpu.prng_random_bits(x.shape) if use_onchip_prng
+                    else bits_ref[pl.ds(r, strip), :])
+            q_int = jnp.floor(yc + _uniform24(bits))
+        else:
+            q_int = jnp.floor(yc + 0.5)
+        # floor(yc + u) >= qmin already; only f32 rounding of yc + u can
+        # pass qmax
+        q_int = jnp.minimum(q_int, qmax)
+        q_ref[pl.ds(r, strip), :] = (q_int * inv_scale).astype(q_ref.dtype)
+        # The stats in grid units: scaling by 2^-FL is exact, so the error
+        # and magnitude sums scale once at the end, and the relative error
+        # needs no scale at all.  yc == 0 gives q_int == 0, so the zero
+        # lanes add 0 / 1.
+        err = jnp.abs(q_int - yc)
+        mag = jnp.abs(yc)
+        nz = mag > 0.0
+        # err / mag: the EUP's reciprocal and one Newton step, ~1 ulp
+        den = jnp.where(nz, mag, 1.0)
+        inv = pl.reciprocal(den, approx=True)
+        inv = inv * (2.0 - den * inv)
+        parts = (jnp.where(nz, 1.0, 0.0), jnp.where(y != yc, 1.0, 0.0),
+                 err, err * inv, mag)
+        return (tuple(a + p for a, p in zip(acc, parts))
+                + (jnp.maximum(acc[5], jnp.abs(x)),))
+
+    acc = tuple(acc_ref[k] for k in range(N_STATS - 1))
+    for r in range(0, bm, strip):
+        acc = body(r, acc)
+    for k in range(N_STATS - 1):
+        acc_ref[k] = acc[k]
+
+    @pl.when(last)
+    def _finish():
+        stats_ref[_IDX_COUNT] = jnp.float32(shape[0] * shape[1])
+        stats_ref[_IDX_NZ] = jnp.sum(acc_ref[0])
+        stats_ref[_IDX_OVER] = jnp.sum(acc_ref[1])
+        stats_ref[_IDX_AERR] = jnp.sum(acc_ref[2]) * inv_scale[0, 0]
+        stats_ref[_IDX_RERR] = jnp.sum(acc_ref[3])
+        stats_ref[_IDX_ASUM] = jnp.sum(acc_ref[4]) * inv_scale[0, 0]
+        stats_ref[_IDX_MAX] = jnp.max(acc_ref[N_STATS - 2])
+
+
+@functools.partial(jax.jit, static_argnames=("stochastic", "use_onchip_prng",
+                                             "interpret"))
+def dps_quant_leaf_pallas(x: jax.Array, fmt3: jax.Array,
+                          bits: jax.Array | None = None,
+                          *, stochastic: bool = True,
+                          use_onchip_prng: bool = False,
+                          interpret: bool = False):
+    """Fused quantize + stats of one ``[rows, cols]`` tree leaf, in place
+    of its shape: read x, write q in x's dtype, nothing else.
+
+    ``fmt3`` = int32[3] = [il, fl, seed].  ``bits`` (uint32, x's shape) is
+    the stochastic rounding's noise on the portable path, and must be
+    ``None`` under ``use_onchip_prng`` or nearest rounding.  No pad, mask
+    or relayout of x: blocks keep the leaf's minor dim (``_leaf_block``),
+    the last block of a ragged dim hangs past the edge and the kernel
+    masks it.  Returns ``(q, stats_vec[7])``, ``stats_vec`` laid out as
+    :func:`dps_quant_pallas`'s.  On the bits path q is bit-exact against
+    ``ref.dps_quant_ref`` and the stats agree to f32 summation order (the
+    relative error's reciprocal to ~1 ulp) for finite x; a NaN counts as
+    overflow here.
+    """
+    if (bits is not None) != (stochastic and not use_onchip_prng):
+        raise ValueError("bits go with stochastic rounding off the on-chip "
+                         "PRNG, and only there")
+    rows, cols = x.shape
+    bm, bn = _leaf_block(rows, cols, 2 * x.dtype.itemsize
+                        + (0 if bits is None else 4))
+    strip = _LEAF_ROW_UNIT if bm % _LEAF_ROW_UNIT == 0 else bm
+    kernel = functools.partial(
+        _leaf_kernel, stochastic=stochastic, use_onchip_prng=use_onchip_prng,
+        shape=(rows, cols), ragged=bool(rows % bm or cols % bn), strip=strip)
+    block = pl.BlockSpec((bm, bn), lambda i, j, *_: (i, j))
+    operands = (x,) if bits is None else (x, bits)
+    q, stats = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(rows, bm), pl.cdiv(cols, bn)),
+            in_specs=[block] * len(operands),
+            out_specs=[block, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.VMEM((N_STATS - 1, strip, bn),
+                                       jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((rows, cols), x.dtype),
+                   jax.ShapeDtypeStruct((N_STATS,), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="dps_quant",
+    )(fmt3, *operands)
+    return q, stats
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,9 @@
 ``interpret=None`` resolves from the platform: Mosaic on a TPU, the Pallas
 interpreter elsewhere (the CPU tests).  ``onchip_prng=True`` selects the
 PRNG-in-kernel variant (TPU only — see kernel docstring).
+
+``dps_quantize_leaf`` is the train step's leaf quantizer on a TPU: one
+fused kernel per weight or gradient leaf, on the leaf's own buffer.
 """
 
 from __future__ import annotations
@@ -17,11 +20,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.fixed_point import FixedPointFormat, QuantStats
+from repro.core.fixed_point import (ROUND_NEAREST, ROUND_STOCHASTIC,
+                                    FixedPointFormat, QuantStats)
 from repro.device import on_tpu
 from repro.kernels import ref as ref_lib
 from repro.kernels.dps_quant import (DEFAULT_BLOCK, DEFAULT_GROUP_QUANTUM,
-                                     dps_quant_pallas,
+                                     dps_quant_leaf_pallas, dps_quant_pallas,
                                      dps_quant_group_wire_pallas,
                                      dps_quant_wire_pallas,
                                      dps_wire_reduce_pallas, group_block)
@@ -216,6 +220,40 @@ def dps_quantize(x: jax.Array, fmt: FixedPointFormat, *,
     return _fold_and_call(dps_quant_pallas, x, fmt, key=key, bits=bits,
                           stochastic=stochastic, onchip_prng=onchip_prng,
                           block=block, interpret=interpret)
+
+
+def dps_quantize_leaf(x: jax.Array, fmt: FixedPointFormat, *,
+                      mode: str = ROUND_STOCHASTIC,
+                      key: jax.Array | None = None):
+    """One tree leaf's quantize event as one fused kernel: the leaf
+    quantizer of ``fixed_point.quantize_tree`` on a TPU.
+
+    Same call and result as ``fixed_point.quantize(x, fmt, mode=mode,
+    key=key)``: ``(q in x's dtype, QuantStats)`` for a scalar ``fmt``.
+    Leading dims fold into rows and the minor dim stays, so on a TPU the
+    kernel reads the leaf's own buffer and its rounding bits come from the
+    core's PRNG, seeded from ``key``.  Elsewhere the kernel is interpreted
+    with the bits operand ``jax.random.bits(key, x.shape)`` that
+    ``quantize`` draws, so q equals ``quantize``'s bit for bit.
+    """
+    if mode not in (ROUND_STOCHASTIC, ROUND_NEAREST):
+        raise ValueError(f"unknown rounding mode {mode!r}")
+    stochastic = mode == ROUND_STOCHASTIC
+    if stochastic and key is None:
+        raise ValueError("stochastic rounding needs `key`")
+    chip = on_tpu()
+    x2 = x.reshape(-1, x.shape[-1]) if x.ndim else x.reshape(1, 1)
+    bits, seed = None, jnp.zeros((), jnp.int32)
+    if stochastic and chip:
+        seed = jax.lax.bitcast_convert_type(
+            jax.random.bits(key, (), jnp.uint32), jnp.int32)
+    elif stochastic:
+        bits = jax.random.bits(key, x.shape, jnp.uint32).reshape(x2.shape)
+    fmt3 = jnp.stack([fmt.il.astype(jnp.int32), fmt.fl.astype(jnp.int32),
+                      seed])
+    q2, vec = dps_quant_leaf_pallas(x2, fmt3, bits, stochastic=stochastic,
+                                    use_onchip_prng=chip, interpret=not chip)
+    return q2.reshape(x.shape), ref_lib.stats_from_vector(vec)
 
 
 def dps_quantize_wire(x: jax.Array, fmt: FixedPointFormat, *,

@@ -14,6 +14,7 @@ since what they would write cannot be read back without a chip.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import dps_quant as dq
+from repro.kernels import ops
 from repro.kernels import paged_attn as pa
 
 D_MODEL, N_HEADS, N_KV, HEAD_DIM, D_FF = 3072, 24, 8, 128, 8192
@@ -74,6 +76,25 @@ def test_global_quant_kernel_compiles(chip, wire, stochastic, prng):
     assert "tpu_custom_call" in txt
 
 
+# the benchmark's bf16 leaves (an MLP weight, the vocab-padded head), and
+# a ragged f32 leaf whose last blocks hang past both edges
+LEAF_SHAPES = [((6144, 16384), jnp.bfloat16), ((6144, 11776), jnp.bfloat16),
+               ((1157, 600), jnp.float32)]
+
+
+@pytest.mark.parametrize("shape, dtype", LEAF_SHAPES,
+                         ids=["mlp", "head", "ragged"])
+@pytest.mark.parametrize("stochastic, prng", ROUNDINGS, ids=ROUNDING_IDS)
+def test_leaf_quant_kernel_compiles(chip, shape, dtype, stochastic, prng):
+    fn = functools.partial(dq.dps_quant_leaf_pallas, stochastic=stochastic,
+                           use_onchip_prng=prng, interpret=False)
+    args = [(shape, dtype), ((3,), jnp.int32)]
+    if stochastic and not prng:
+        args.append((shape, jnp.uint32))
+    txt = _compile_text(fn, chip, *args)
+    assert "tpu_custom_call" in txt
+
+
 @pytest.mark.parametrize("stochastic, prng", ROUNDINGS, ids=ROUNDING_IDS)
 def test_group_wire_kernel_compiles(chip, stochastic, prng):
     n = sum(GROUP_SIZES)
@@ -110,3 +131,97 @@ def test_paged_attn_kernel_compiles(chip, page_size):
                         ((slots, pages_per_seq), jnp.int32),
                         ((slots,), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+# A small DPS config of the benchmark's model family (InternVL2's language
+# model at cut widths, bf16 weights).  At these sizes XLA prefetches some
+# leaves into VMEM ahead of their kernel, a copy that keeps shape and
+# layout; at the benchmark's sizes the leaves stay in HBM.
+def _small_vlm():
+    import dataclasses
+
+    from repro.configs.internvl2_26b import CONFIG
+    return dataclasses.replace(
+        CONFIG, n_layers=2, d_model=2048, n_heads=16, n_kv_heads=8,
+        head_dim=128, d_ff=4096, vocab=4096, n_patches=16, train_accum=1)
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
+_OPERANDS = re.compile(r"custom-call\(([^)]*)\)")
+_OPCODE = re.compile(r"(?<![\w\-.%])([a-z][a-z0-9\-]*)\(")
+
+
+def test_train_step_feeds_each_fused_kernel_its_leaf(chip, monkeypatch):
+    """On a TPU the DPS train step quantizes the weight and gradient trees
+    with one fused kernel per quantized leaf and event (snap, gradient,
+    re-snap), and feeds each its leaf as it lies: no bits or mask operand,
+    no copy, pad or relayout of the leaf."""
+    from repro.configs.base import ShapeConfig
+    from repro.core import qtrain
+    from repro.launch import specs
+    from repro.models import registry
+    from repro.models.common import abstract_params
+    from repro.optim import AdamWConfig, make_optimizer
+
+    # the step decides from the platform, which here is the CPU
+    monkeypatch.setattr(qtrain, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = _small_vlm()
+    qcfg = qtrain.QuantConfig()
+    opt = make_optimizer(AdamWConfig())
+    step = specs.build_train_step(cfg, qcfg, opt)
+    assert step.fused_quant_active
+    state = specs.abstract_train_state(cfg, opt, qcfg)
+    batch = specs.train_batch_specs(cfg, ShapeConfig("t", "train", 256, 1))
+    put = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
+    txt = jax.jit(step).lower(put(state), put(batch)).compile().as_text()
+
+    pred = qcfg.policy.param_predicate()
+    leaves = jax.tree_util.tree_flatten_with_path(
+        abstract_params(registry(cfg.family).model_defs(cfg)))[0]
+    n_quant = sum(1 for p, l in leaves if pred(p, l))
+    assert n_quant < len(leaves)             # the norms stay out
+
+    defs = {}
+    for line in txt.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            defs[m.group(1)] = m.group(2)
+
+    def opcode(rest):
+        return _OPCODE.search(rest).group(1)
+
+    def arg(rest):
+        return re.search(r"\(%([\w.\-]+)\)", rest).group(1)
+
+    def producer(name):
+        """The instruction behind bitcasts and prefetches: async copies
+        into another memory space that keep the shape and the layout."""
+        while True:
+            rest = defs[name]
+            if opcode(rest) == "bitcast":
+                name = arg(rest)
+            elif opcode(rest) == "copy-done":
+                src = arg(defs[arg(rest)])
+                same = lambda r: re.sub(r"S\(\d+\)", "", r.split(" ")[0])
+                if same(rest) != same(defs[src]):
+                    return name, rest
+                name = src
+            else:
+                return name, rest
+
+    calls = [rest for rest in defs.values()
+             if "tpu_custom_call" in rest and "custom-call(" in rest]
+    assert len(calls) == 3 * n_quant
+    for rest in calls:
+        operands = [o.strip().lstrip("%")
+                    for o in _OPERANDS.search(rest).group(1).split(",")]
+        assert len(operands) == 2, rest[:200]   # [il, fl, seed] and x
+        name, src = producer(operands[1])
+        assert opcode(src) not in (
+            "copy", "copy-start", "copy-done", "pad", "concatenate",
+            "reshape", "transpose", "slice", "dynamic-slice"), \
+            (name, src[:200])
+        assert not re.search(r"copy|pad|concatenate|transpose", name), \
+            (name, src[:200])
