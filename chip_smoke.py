@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Chip smoke test: the main paths once on a TPU, at llama3_2_3b's widths.
 
-    python chip_smoke.py              one chip: kernels, train, serve
+    python chip_smoke.py              one chip: kernels, SSD scan, train,
+                                      serve
     python chip_smoke.py --chips 4    four chips: data-parallel training with
                                       the int8 gradient wire against fp32
     python chip_smoke.py --rehearse   the same phases on the CPU at smoke
@@ -58,6 +59,13 @@ PRNG_MEAN_ERR_STEPS = 1e-2
 # ... and its abs-error sum matches the bits-operand run's to sampling
 # noise (relative std ~ 1/sqrt(N)).
 PRNG_ABS_ERR_RTOL = 2e-2
+# SSD scan: the program rounds x * dt to bf16 (2^-9) before its f32
+# einsums, which the chip runs at its default matmul precision, while the
+# reference keeps f32 at HIGHEST.  Over sums of up to 256 products with
+# random signs that leaves 4.0e-3 (values) and 6.0e-3 (gradient) of the
+# largest value on the CPU at the small shapes; 2e-2 leaves room for the
+# chip's precision, and an overflow shows as inf or NaN.
+SSD_RTOL = 2e-2
 # Four-chip check: the int8 wire adds rounding noise of at most one wire
 # grid step per gradient element; over a few AdamW steps the two loss
 # curves must stay within 1% of each other at every step.
@@ -289,6 +297,57 @@ def tree_quant_check(small: bool, key):
             f"over {int(s_k.count)} weights")
 
 
+def ssd_scan_check(small: bool):
+    """``models/ssm.py`` ``ssd_scan`` against the plain reference's ``ssd``
+    at the benchmark's ``mamba2-1.3b-l16`` per-layer shapes (4 rows of
+    2048, 64 heads of 64, state 128, chunk 256), with every chunk's summed
+    decay far past 88.7, where exp of a positive segment sum overflows:
+    the values and the gradient with respect to x are finite and agree.
+    ``small`` keeps the chunk and cuts the rest."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench.reference import mamba2 as ref_ssm
+    from repro.configs.base import get_config
+    from repro.models import ssm
+
+    B, S, H, P, N, Q = (1, 512, 4, 8, 16, 256) if small else \
+        (4, 2048, 64, 64, 128, 256)
+    cfg = dataclasses.replace(get_config("mamba2_1_3b"), ssm_chunk=Q)
+    kx, kb, kc, kd, kw = jax.random.split(jax.random.key(15), 5)
+    bf16 = lambda k, s: jax.random.normal(k, s).astype(jnp.bfloat16)
+    x, b, c = bf16(kx, (B, S, H, P)), bf16(kb, (B, S, N)), bf16(kc, (B, S, N))
+    dt = jax.nn.softplus(jax.random.normal(kd, (B, S, H)))
+    a = -jnp.ones((H,))
+    w = jax.random.normal(kw, (B, S, H, P))
+    decay = float(jnp.min(jnp.sum(dt.reshape(B, S // Q, Q, H), axis=2)))
+
+    def program(x):
+        return ssm.ssd_scan(cfg, x, b, c, dt, dt * a)[0].astype(jnp.float32)
+
+    def reference(x):
+        mm = lambda s, *ops: jnp.einsum(s, *ops,
+                                        precision=jax.lax.Precision.HIGHEST)
+        f32 = lambda t: t.astype(jnp.float32)
+        return ref_ssm.ssd(f32(x), dt, a, f32(b), f32(c), Q, mm)
+
+    def value_and_grad(f):
+        y, vjp = jax.jit(lambda x: jax.vjp(f, x))(x)
+        return np.asarray(y), np.asarray(jax.jit(vjp)(w)[0], np.float32)
+
+    gaps = []
+    for name, (got, want) in zip(("y", "dy/dx"), zip(
+            value_and_grad(program), value_and_grad(reference))):
+        _check(np.all(np.isfinite(got)), f"ssd scan: non-finite {name}")
+        gap = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+        _check(gap <= SSD_RTOL, f"ssd scan: {name} rel gap {gap:.2e}")
+        gaps.append(f"{name} rel {gap:.1e}")
+    return f"ssd scan, least chunk decay {decay:.0f}: " + ", ".join(gaps)
+
+
 # ---------------------------------------------------------------------------
 # train and serve through their CLIs
 # ---------------------------------------------------------------------------
@@ -435,6 +494,7 @@ def main(argv=None) -> int:
         _run_phase("four_chip_train", lambda: four_chip_phase(small), results)
     else:
         _run_phase("kernels", lambda: kernels_phase(small), results)
+        _run_phase("ssd_scan", lambda: ssd_scan_check(small), results)
         _run_phase("train", lambda: train_phase(small), results)
         _run_phase("serve", lambda: serve_phase(small), results)
 

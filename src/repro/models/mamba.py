@@ -1,7 +1,7 @@
 """Attention-free Mamba2 LM (the ``ssm`` family; mamba2-1.3b).
 
 Embed → L × [pre-norm residual SSD block] → final norm → unembed.
-Decode state is O(1) per token, so the ``long_500k`` cell runs here.
+Decode state is O(1) per token, whatever the context length.
 """
 
 from __future__ import annotations

@@ -5,7 +5,9 @@ sequence is split into chunks of ``ssm_chunk``; within a chunk the output is
 a masked (decay-weighted) attention-like matmul, across chunks a small
 recurrence carries the (H, P, N) state.  Train/prefill cost is
 O(S·Q·(P+N)) — sub-quadratic in S — and decode is an O(1) state update,
-which is why the ssm/hybrid archs own the ``long_500k`` cell.
+which is why the ssm and hybrid configs set ``supports_long``.  The scan's
+body runs under the ``ssm.scan`` name scope, so its device time can be told
+from the projections' in a profile.
 
 Numerics: the recurrent state, per-step decays, A_log and dt_bias stay fp32
 (policy carve-out — fixed-point emulation of a 500k-step recurrence
@@ -110,6 +112,24 @@ def ssd_scan(cfg: ModelConfig, x: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
         S = S + pad
     nc = S // Q
 
+    with jax.named_scope("ssm.scan"):
+        y, h_final = _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc, Q)
+    return y[:, :S_orig].astype(x.dtype), h_final
+
+
+def _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc: int, Q: int):
+    """The scan over ``nc`` whole chunks of ``Q`` steps: the intra-chunk
+    quadratic form, the chunk states, the recurrence over chunks and the
+    states' contribution to each position.
+
+    Every ``exp`` takes a sum of log-decays (each <= 0), so it is at most
+    1.  Above the diagonal the intra-chunk segment sum cum_t - cum_s is
+    positive instead; it is masked to -inf before the ``exp``, because past
+    a summed decay of 88.7 in one chunk it overflows to inf, and inf * 0 is
+    NaN in the values and in the gradients."""
+    B, S, H, Pd = x.shape
+    N = b_mat.shape[-1]
+
     xr = (x * dt[..., None].astype(x.dtype)).reshape(B, nc, Q, H, Pd)
     br = b_mat.reshape(B, nc, Q, N)
     cr = c_mat.reshape(B, nc, Q, N)
@@ -125,8 +145,8 @@ def ssd_scan(cfg: ModelConfig, x: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
     cb = jnp.einsum("bcqn,bckn->bcqk", cr.astype(jnp.float32),
                     br.astype(jnp.float32))
     rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H) t-s
-    tri = jnp.tril(jnp.ones((Q, Q), jnp.float32))
-    decay_m = jnp.exp(rel) * tri[None, None, :, :, None]
+    tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
+    decay_m = jnp.exp(jnp.where(tri, rel, -jnp.inf))
     m = cb[..., None] * decay_m                          # (B,nc,Q,Q,H)
     y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", m, xr.astype(jnp.float32))
 
@@ -151,8 +171,7 @@ def ssd_scan(cfg: ModelConfig, x: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
 
     y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp", cr.astype(jnp.float32),
                          jnp.exp(cum), h_prevs)
-    y = (y_intra + y_inter).reshape(B, S, H, Pd)[:, :S_orig]
-    return y.astype(x.dtype), h_final
+    return (y_intra + y_inter).reshape(B, S, H, Pd), h_final
 
 
 def ssm_apply(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array, *,
