@@ -7,7 +7,9 @@ recurrence carries the (H, P, N) state.  Train/prefill cost is
 O(S·Q·(P+N)) — sub-quadratic in S — and decode is an O(1) state update,
 which is why the ssm and hybrid configs set ``supports_long``.  The scan's
 body runs under the ``ssm.scan`` name scope, so its device time can be told
-from the projections' in a profile.
+from the projections' in a profile.  On a single TPU the intra-chunk
+quadratic form runs as the Pallas kernels of ``kernels/ssd.py`` where their
+tiles fit; everywhere else, and as their reference, in jnp.
 
 Numerics: the recurrent state, per-step decays, A_log and dt_bias stay fp32
 (policy carve-out — fixed-point emulation of a 500k-step recurrence
@@ -16,6 +18,7 @@ underflows at 2^-FL; the paper's §5 anticipates exactly this failure mode).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -23,8 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.dist.sharding import logical_constraint
+from repro.device import on_tpu
+from repro.dist.sharding import current_mesh_rules, logical_constraint
+from repro.kernels import ssd as ssd_kernel
 from repro.models.common import ParamDef, rms_norm
+
+SCAN_SCOPE = "ssm.scan"     # the scan's ops, for a profile (bench/ssm_scan.py)
 
 
 def d_inner(cfg: ModelConfig) -> int:
@@ -112,15 +119,58 @@ def ssd_scan(cfg: ModelConfig, x: jax.Array, b_mat: jax.Array, c_mat: jax.Array,
         S = S + pad
     nc = S // Q
 
-    with jax.named_scope("ssm.scan"):
-        y, h_final = _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc, Q)
+    intra = ssd_intra if intra_kernel_fits(Q, H, Pd) else None
+    with jax.named_scope(SCAN_SCOPE):
+        y, h_final = _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc, Q,
+                                 intra)
     return y[:, :S_orig].astype(x.dtype), h_final
 
 
-def _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc: int, Q: int):
+def intra_kernel_fits(Q: int, H: int, P: int) -> bool:
+    """Whether ``ssd_scan`` runs the intra-chunk form as the Pallas kernels:
+    on a TPU, with no multi-device mesh (under one, a ``pallas_call`` is not
+    partitioned), at a chunk and head width the kernels tile."""
+    mesh, _ = current_mesh_rules()
+    return (on_tpu() and (mesh is None or mesh.devices.size == 1)
+            and ssd_kernel.fits(Q, H, P))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def ssd_intra(x, b, c, cum, h, chunk, dot_dtype=jnp.bfloat16):
+    """The scan's output as ``kernels/ssd.py`` computes it from the states
+    each chunk starts from, in x's dtype, over (B, features, S) views (see
+    ``intra_fwd``).  Off a TPU the kernels run in the Pallas interpreter."""
+    return ssd_kernel.intra_fwd(x, b, c, cum, h, chunk, dot_dtype=dot_dtype,
+                                interpret=not on_tpu())
+
+
+# The rules are traced apart from the caller's name stack, where a scope
+# reaches an op only as ``jvp(ssm.scan)``; each opens the scan's scope
+# itself, so that the kernels' own ``op_name`` carries it.
+def _ssd_intra_fwd(x, b, c, cum, h, chunk, dot_dtype):
+    with jax.named_scope(SCAN_SCOPE):
+        y = ssd_kernel.intra_fwd(x, b, c, cum, h, chunk, dot_dtype=dot_dtype,
+                                 interpret=not on_tpu())
+    return y, (x, b, c, cum, h)
+
+
+def _ssd_intra_bwd(chunk, dot_dtype, res, dy):
+    with jax.named_scope(SCAN_SCOPE):
+        return ssd_kernel.intra_bwd(*res, dy, chunk, dot_dtype=dot_dtype,
+                                    interpret=not on_tpu())
+
+
+ssd_intra.defvjp(_ssd_intra_fwd, _ssd_intra_bwd)
+
+
+def _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc: int, Q: int,
+                intra=None):
     """The scan over ``nc`` whole chunks of ``Q`` steps: the intra-chunk
     quadratic form, the chunk states, the recurrence over chunks and the
-    states' contribution to each position.
+    states' contribution to each position.  ``intra`` computes the
+    quadratic form and the states' term from the states each chunk starts
+    from (``ssd_intra``); None forms both in jnp, with the quadratic
+    form's (B, nc, Q, Q, H) tensors in memory.
 
     Every ``exp`` takes a sum of log-decays (each <= 0), so it is at most
     1.  Above the diagonal the intra-chunk segment sum cum_t - cum_s is
@@ -142,13 +192,14 @@ def _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc: int, Q: int):
     total = cum[:, :, -1]                               # (B,nc,H)
 
     # --- intra-chunk (quadratic in Q only) ---
-    cb = jnp.einsum("bcqn,bckn->bcqk", cr.astype(jnp.float32),
-                    br.astype(jnp.float32))
-    rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H) t-s
-    tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
-    decay_m = jnp.exp(jnp.where(tri, rel, -jnp.inf))
-    m = cb[..., None] * decay_m                          # (B,nc,Q,Q,H)
-    y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", m, xr.astype(jnp.float32))
+    if intra is None:
+        cb = jnp.einsum("bcqn,bckn->bcqk", cr.astype(jnp.float32),
+                        br.astype(jnp.float32))
+        rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H)
+        tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
+        decay_m = jnp.exp(jnp.where(tri, rel, -jnp.inf))
+        m = cb[..., None] * decay_m                          # (B,nc,Q,Q,H)
+        y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", m, xr.astype(jnp.float32))
 
     # --- chunk states ---
     w_state = jnp.exp(total[:, :, None, :] - cum)        # (B,nc,Q,H)
@@ -167,8 +218,16 @@ def _ssd_chunks(x, b_mat, c_mat, dt, log_decay, h0, nc: int, Q: int):
 
     (h_final, h_prevs) = jax.lax.scan(
         step, h0, (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(total, 1, 0)))
-    h_prevs = jnp.moveaxis(h_prevs, 0, 1)                # (B,nc,H,P,N)
 
+    if intra is not None:
+        # the kernels take (features, positions) views: the layout the
+        # compiled step keeps these activations in; the states as the
+        # scan stacks them
+        t = lambda a: a.reshape(B, S, -1).transpose(0, 2, 1)
+        y = intra(t(xr), t(br), t(cr), cum.reshape(B, S, H),
+                  h_prevs.reshape(nc, B, H * Pd, N), Q)
+        return y.transpose(0, 2, 1).reshape(B, S, H, Pd), h_final
+    h_prevs = jnp.moveaxis(h_prevs, 0, 1)                # (B,nc,H,P,N)
     y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp", cr.astype(jnp.float32),
                          jnp.exp(cum), h_prevs)
     return (y_intra + y_inter).reshape(B, S, H, Pd), h_final

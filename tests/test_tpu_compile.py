@@ -225,3 +225,42 @@ def test_train_step_feeds_each_fused_kernel_its_leaf(chip, monkeypatch):
             (name, src[:200])
         assert not re.search(r"copy|pad|concatenate|transpose", name), \
             (name, src[:200])
+
+
+# the Mamba2 benchmark cell's scan, one layer: 4 x 2048 positions in
+# chunks of 256, 64 heads of 64, state 128
+SSD_B, SSD_S, SSD_Q, SSD_H, SSD_P, SSD_N = 4, 2048, 256, 64, 64, 128
+
+
+def test_ssd_intra_kernels_compile(chip, monkeypatch):
+    """The SSD scan's value and gradients at the Mamba2 cell's per-layer
+    shapes in its dtypes, as ``ssd_scan`` runs them on a TPU: two Mosaic
+    calls, the forward and the backward kernel, each carrying the scan's
+    scope in its own op_name."""
+    import dataclasses
+
+    from repro.configs.base import get_config
+    from repro.models import ssm
+
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("mamba2_1_3b"), ssm_chunk=SSD_Q)
+
+    def value_and_grads(x, b, c, dt, ld, w):
+        loss = lambda *a: jnp.sum(
+            ssm.ssd_scan(cfg, *a)[0].astype(jnp.float32) * w)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+            x, b, c, dt, ld)
+
+    B, S, H, P, N = SSD_B, SSD_S, SSD_H, SSD_P, SSD_N
+    txt = _compile_text(value_and_grads, chip,
+                        ((B, S, H, P), jnp.bfloat16),
+                        ((B, S, N), jnp.bfloat16),
+                        ((B, S, N), jnp.bfloat16),
+                        ((B, S, H), jnp.float32),
+                        ((B, S, H), jnp.float32),
+                        ((B, S, H, P), jnp.float32))
+    calls = [l for l in txt.splitlines() if "tpu_custom_call" in l
+             and "custom-call(" in l]
+    assert len(calls) == 2
+    for name in ("ssd_intra_fwd", "ssd_intra_bwd"):
+        assert sum(f"/ssm.scan/{name}/" in l for l in calls) == 1, name
